@@ -11,16 +11,14 @@ import jax.numpy as jnp
 from repro.configs import get_arch
 from repro.data import DataConfig, FederatedData
 from repro.dfl import DFLConfig, DFLTrainer
+from repro.launch.mesh import make_local_mesh
 from repro.models import Batch, build_model
 
 
 def run(csv_rows):
-    import numpy as np
-
     cfg = get_arch("smollm-360m").smoke_variant()
     model = build_model(cfg)
-    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1),
-                             ("data", "model"))
+    mesh = make_local_mesh((1, 1), ("data", "model"))
     data = FederatedData(DataConfig(vocab=cfg.vocab, seq_len=64,
                                     batch_per_node=4, n_nodes=1))
     tok, lab = data.global_batch()

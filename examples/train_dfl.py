@@ -1,6 +1,8 @@
 """End-to-end DFL training driver: 4 silos with non-IID data, local steps +
-MOSGU gossip every step, on an emulated (pod, data, model) mesh.
+MOSGU gossip every step, on a (pod, data, model) = 2x2x2 mesh. On the CPU,
+emulate the eight devices:
 
+  XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
   PYTHONPATH=src python examples/train_dfl.py [--steps 200] [--d-model 512]
 
 This is the CPU-scale version of the production flow in
@@ -14,10 +16,7 @@ scenario: its protocol picks the gossip mode and its churn schedule fires
 at the pinned rounds (replan + recompile on membership change).
 """
 import argparse
-import os
 import time
-
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import jax
 import jax.numpy as jnp
@@ -55,9 +54,10 @@ def main():
     from repro.configs import get_arch
     from repro.data import DataConfig, FederatedData
     from repro.dfl import DFLConfig, DFLTrainer
+    from repro.launch.mesh import make_local_mesh
     from repro.models import Batch, build_model
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_local_mesh((2, 2, 2), ("pod", "data", "model"))
     cfg = get_arch("smollm-360m").replace(
         n_layers=args.layers, d_model=args.d_model, n_heads=8, n_kv_heads=4,
         head_dim=64, d_ff=2 * args.d_model, vocab=args.vocab,

@@ -1,10 +1,10 @@
-"""smollm-360m — llama-arch small dense GQA [hf:HuggingFaceTB/SmolLM-135M]."""
+"""smollm-360m — llama-arch small dense GQA [hf:HuggingFaceTB/SmolLM-360M]."""
 from .base import ArchConfig, register
 
 SMOLLM_360M = register(ArchConfig(
     name="smollm-360m",
     family="dense",
-    source="hf:HuggingFaceTB/SmolLM-135M",
+    source="hf:HuggingFaceTB/SmolLM-360M",
     n_layers=32,
     d_model=960,
     n_heads=15,

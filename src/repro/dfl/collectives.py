@@ -53,17 +53,6 @@ from ..core.schedule import (
 PyTree = Any
 
 
-def _shard_map(body, mesh, in_specs, out_specs):
-    """jax.shard_map moved between releases; accept both spellings."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm_exp
-    return sm_exp(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-
-
 # ---------------------------------------------------------------------------
 # node topology on the TPU mesh
 # ---------------------------------------------------------------------------
@@ -154,16 +143,10 @@ class GossipPlan:
         )
 
 
-def _axis_size(a) -> jax.Array:
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(a)
-    return jax.lax.psum(jnp.ones((), jnp.int32), a)  # pre-0.5 jax
-
-
 def _node_index(node_axes: Sequence[str]) -> jax.Array:
     idx = jnp.zeros((), jnp.int32)
     for a in node_axes:
-        idx = idx * _axis_size(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx
 
 
@@ -319,7 +302,7 @@ def _dissemination_body(plan: GossipPlan, theta: PyTree, codec=None,
 
 
 def _segmented_body(plan: GossipPlan, theta: PyTree, codec=None) -> PyTree:
-    """Segmented gossip: each leaf is split into S flat segments; the buffer
+    """Segmented gossip: each leaf is split into S contiguous segments; the buffer
     holds N·S segment slots (slot k = owner k//S, segment k%S) and the
     compiled segmented plan moves one segment per transfer. After full
     dissemination every node reassembles all N models and takes the mean.
@@ -332,6 +315,13 @@ def _segmented_body(plan: GossipPlan, theta: PyTree, codec=None) -> PyTree:
     n, S = plan.n_nodes, plan.n_segments
 
     def split(t):
+        # Segment k is the k-th of S equal contiguous ranges of the flat
+        # leaf. Where the leading axis divides by S, split that axis: on the
+        # TPU it moves no data, while flattening a tiled leaf relayouts it,
+        # and the TPU compiler's time for a relayout grows with the leaf
+        # (minutes per program at smollm-360m widths).
+        if t.ndim and t.shape[0] % S == 0:
+            return t.reshape(S, t.shape[0] // S, *t.shape[1:])
         flat = t.reshape(-1)
         pad = (-flat.shape[0]) % S
         if pad:
@@ -339,16 +329,19 @@ def _segmented_body(plan: GossipPlan, theta: PyTree, codec=None) -> PyTree:
         return flat.reshape(S, -1)
 
     def init_buf(t):
-        segs = split(t)  # (S, L)
-        buf = jnp.zeros((n * S, segs.shape[1]), segs.dtype)
-        return jax.lax.dynamic_update_slice(buf, segs, (row * S, 0))
+        segs = split(t)  # (S, *segment)
+        buf = jnp.zeros((n * S, *segs.shape[1:]), segs.dtype)
+        return jax.lax.dynamic_update_slice(
+            buf, segs, (row * S,) + (0,) * (segs.ndim - 1))
 
     buf = jax.tree.map(init_buf, theta)
     buf = _apply_perm_steps(plan.seg_steps, buf, ax, nid, codec=codec)
 
     def reassemble_mean(b, t):
-        models = b.reshape(n, S * b.shape[1])[:, : t.size]  # (N, |t|)
-        mean = jnp.mean(models.astype(jnp.float32), axis=0)
+        models = b.reshape(n, S, *b.shape[1:]).astype(jnp.float32)
+        mean = jnp.mean(models, axis=0)  # (S, *segment)
+        if mean.size != t.size:  # flattened and padded
+            mean = mean.reshape(-1)[: t.size]
         return mean.reshape(t.shape).astype(t.dtype)
 
     out = jax.tree.map(reassemble_mean, buf, theta)
@@ -461,8 +454,8 @@ def gossip_exchange(
             mean, _, new_ef = _dissemination_body(plan, theta, codec=codec, ef=ef)
             return mean, new_ef
 
-        fn = _shard_map(ef_body, mesh, (param_specs, param_specs),
-                        (param_specs, param_specs))
+        fn = jax.shard_map(ef_body, mesh=mesh, in_specs=(param_specs, param_specs),
+                           out_specs=(param_specs, param_specs), check_vma=False)
         return fn(params, ef_state)
     if mode == "tree_allreduce" and (wire_dtype is not None or codec is not None):
         body = partial(_tree_allreduce_body, plan, wire_dtype=wire_dtype,
@@ -474,7 +467,8 @@ def gossip_exchange(
         body = partial(GOSSIP_BODIES[mode], plan, codec=codec)
     else:
         body = partial(GOSSIP_BODIES[mode], plan)
-    fn = _shard_map(body, mesh, (param_specs,), param_specs)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(param_specs,),
+                       out_specs=param_specs, check_vma=False)
     return fn(params)
 
 

@@ -6,6 +6,10 @@ directions are bandwidth-bound element-wise passes, so each grid program
 streams a ``(block_c, chunk)`` tile of chunk-rows through VMEM and emits the
 codes and scales in one read of the input: HBM traffic is exactly
 input + output, with the absmax reduction and the scale divide fused.
+
+Scales travel through the kernels as a ``(C, 1)`` column: Mosaic takes a 2-D
+``(block_c, 1)`` block (the last dim equals the array's), while a rank-1
+``(block_c,)`` block must be the whole array or a multiple of 128.
 """
 from __future__ import annotations
 
@@ -18,16 +22,16 @@ from jax.experimental import pallas as pl
 
 def _quant_kernel(x_ref, q_ref, s_ref, *, qmax: float):
     x = x_ref[...].astype(jnp.float32)  # (block_c, chunk)
-    absmax = jnp.max(jnp.abs(x), axis=1)
-    scale = jnp.where(absmax > 0, absmax / qmax, 1.0)
-    q = jnp.clip(jnp.round(x / scale[:, None]), -qmax, qmax)
+    absmax = jnp.max(jnp.abs(x), axis=1, keepdims=True)
+    scale = jnp.where(absmax > 0, absmax / qmax, 1.0)  # (block_c, 1)
+    q = jnp.clip(jnp.round(x / scale), -qmax, qmax)
     q_ref[...] = q.astype(jnp.int8)
     s_ref[...] = scale
 
 
 def _dequant_kernel(q_ref, s_ref, o_ref):
     q = q_ref[...].astype(jnp.float32)  # (block_c, chunk)
-    o_ref[...] = q * s_ref[...][:, None].astype(jnp.float32)
+    o_ref[...] = q * s_ref[...].astype(jnp.float32)  # scales (block_c, 1)
 
 
 def _pad_rows(a: jax.Array, block_c: int) -> jax.Array:
@@ -55,15 +59,15 @@ def quantize_chunks(
         in_specs=[pl.BlockSpec((block_c, chunk), lambda i: (i, 0))],
         out_specs=[
             pl.BlockSpec((block_c, chunk), lambda i: (i, 0)),
-            pl.BlockSpec((block_c,), lambda i: (i,)),
+            pl.BlockSpec((block_c, 1), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((cp, chunk), jnp.int8),
-            jax.ShapeDtypeStruct((cp,), jnp.float32),
+            jax.ShapeDtypeStruct((cp, 1), jnp.float32),
         ],
         interpret=interpret,
     )(xp)
-    return codes[:c], scales[:c]
+    return codes[:c], scales[:c, 0]
 
 
 def dequantize_chunks(
@@ -75,14 +79,14 @@ def dequantize_chunks(
 ) -> jax.Array:
     c, chunk = codes.shape
     block_c = min(block_c, c)
-    qp, sp = _pad_rows(codes, block_c), _pad_rows(scales, block_c)
+    qp, sp = _pad_rows(codes, block_c), _pad_rows(scales[:, None], block_c)
     cp = qp.shape[0]
     out = pl.pallas_call(
         _dequant_kernel,
         grid=(cp // block_c,),
         in_specs=[
             pl.BlockSpec((block_c, chunk), lambda i: (i, 0)),
-            pl.BlockSpec((block_c,), lambda i: (i,)),
+            pl.BlockSpec((block_c, 1), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((block_c, chunk), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((cp, chunk), jnp.float32),

@@ -10,10 +10,14 @@ Each grid program owns a ``(block_c, block)`` tile of block-rows and runs two
 fused O(k·block) vector phases with no HBM round-trips in between:
 
 1. **select** — k iterations of masked argmax (first-maximum semantics, so
-   ties go to the lower index, matching ``lax.top_k`` in the oracle);
-2. **pack** — the selected mask is converted to ascending-index order with a
-   cumsum ranking, and the j-th packed column is extracted with a
-   where-reduction (no gather/scatter inside the kernel).
+   ties go to the lower index, matching ``lax.top_k`` in the oracle): the
+   first maximum is the smallest column index where the row max is reached;
+2. **pack** — k iterations that extract the smallest still-unpacked selected
+   column, so the pairs come out in ascending index order; the value is
+   taken with a where-reduction (no gather/scatter inside the kernel).
+
+Both phases use only lane reductions (max, min, sum) and selects, which
+Mosaic lowers; ``cumsum`` has no TPU lowering.
 """
 from __future__ import annotations
 
@@ -27,21 +31,32 @@ from jax.experimental import pallas as pl
 
 def _topk_kernel(x_ref, v_ref, i_ref, *, k: int):
     x = x_ref[...].astype(jnp.float32)  # (block_c, block)
+    block = x.shape[1]
     mag = jnp.abs(x)
     cols = lax.broadcasted_iota(jnp.int32, x.shape, 1)
+
+    def first_col(mask):  # (block_c, 1): lowest column where mask holds
+        return jnp.min(jnp.where(mask, cols, block), axis=1, keepdims=True)
+
     # phase 1: k rounds of "first position achieving the row max"
     sel = jnp.zeros(x.shape, jnp.bool_)
     for _ in range(k):
-        is_max = mag == jnp.max(mag, axis=1, keepdims=True)
-        first = is_max & (jnp.cumsum(is_max.astype(jnp.int32), axis=1) == 1)
+        first = cols == first_col(mag == jnp.max(mag, axis=1, keepdims=True))
         sel = sel | first
         mag = jnp.where(first, -1.0, mag)
-    # phase 2: pack in ascending index order (rank = cumsum of the mask)
-    rank = jnp.cumsum(sel.astype(jnp.int32), axis=1)
+    # phase 2: pack in ascending index order (next-smallest selected column)
+    slot = lax.broadcasted_iota(jnp.int32, (x.shape[0], k), 1)
+    vals = jnp.zeros((x.shape[0], k), jnp.float32)
+    idx = jnp.zeros((x.shape[0], k), jnp.int32)
     for j in range(k):
-        hit = sel & (rank == j + 1)
-        v_ref[:, j] = jnp.sum(jnp.where(hit, x, 0.0), axis=1)
-        i_ref[:, j] = jnp.sum(jnp.where(hit, cols, 0), axis=1).astype(jnp.int32)
+        col = first_col(sel)
+        hit = cols == col
+        v = jnp.sum(jnp.where(hit, x, 0.0), axis=1, keepdims=True)
+        vals = jnp.where(slot == j, v, vals)
+        idx = jnp.where(slot == j, col, idx)
+        sel = sel & ~hit
+    v_ref[...] = vals
+    i_ref[...] = idx
 
 
 def topk_select_blocks(

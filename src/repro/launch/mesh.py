@@ -33,9 +33,21 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_local_mesh(shape=(1, 1), axes=("data", "model")):
-    """Degenerate mesh over however many real devices exist (smoke/bench)."""
+    """Mesh over the first ``prod(shape)`` devices of the default backend.
+
+    Raises when fewer devices exist than the mesh asks for, so a run never
+    silently shrinks its mesh. Axis types are ``Auto`` explicitly:
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, under which the
+    vocab-sharded embedding gather (``models/layers.py``) is rejected.
+    """
     import jax
+    from jax.sharding import AxisType
 
     n = int(np.prod(shape))
-    devices = jax.devices()[:n]
-    return jax.sharding.Mesh(np.asarray(devices).reshape(shape), axes)
+    devices = jax.devices()
+    if len(devices) < n:
+        raise RuntimeError(
+            f"mesh {tuple(shape)} needs {n} devices, found {len(devices)} "
+            f"({devices[0].platform})")
+    return jax.sharding.Mesh(np.asarray(devices[:n]).reshape(shape), tuple(axes),
+                             axis_types=(AxisType.Auto,) * len(axes))

@@ -1,9 +1,11 @@
 """End-to-end DFL training driver.
 
-Runs real steps on whatever devices exist (CPU smoke: reduced arch variant;
-TPU: full config), with MOSGU gossip every step, checkpointing, and
-moderator rotation each communication round.
+Runs real steps on the devices of the default backend (CPU smoke: reduced
+arch variant; TPU: full config), with MOSGU gossip every step,
+checkpointing, and moderator rotation each communication round. A mesh
+needs that many devices; to rehearse on the CPU, give it virtual ones:
 
+  XLA_FLAGS=--xla_force_host_platform_device_count=4 JAX_PLATFORMS=cpu \
   PYTHONPATH=src python -m repro.launch.train --arch smollm-360m --smoke \
       --steps 50 --mesh 1x2x2 --gossip tree_allreduce
 
@@ -14,7 +16,7 @@ schedule fires inside :class:`repro.dfl.session.DFLSession` (replan +
 recompile on every membership change, moderator rotation every round):
 
   PYTHONPATH=src python -m repro.launch.train --arch smollm-360m --smoke \
-      --mesh 1x4x2 --scenario churn_storm
+      --mesh 1x4x2 --scenario churn_storm   # 8 devices
 
 With ``--sweep NAME`` the run is one cell of a registered experiment grid
 (:mod:`repro.scenario.sweep`) — the launcher-array pattern: ``--cell K``
@@ -24,14 +26,12 @@ its plan-executor accounting (a dry-run of the whole table) and exits:
 
   PYTHONPATH=src python -m repro.launch.train --sweep codec_x_protocol
   PYTHONPATH=src python -m repro.launch.train --smoke --mesh 1x4x2 \
-      --sweep codec_x_protocol --cell 3
+      --sweep codec_x_protocol --cell 3     # 8 devices
 """
 from __future__ import annotations
 
 import argparse
 import time
-
-import numpy as np
 
 
 def main() -> None:
@@ -99,13 +99,6 @@ def main() -> None:
         print(f"sweep {sweep.name!r} cell {args.cell}: "
               f"{sweep_cell.spec.name}")
 
-    if args.mesh:
-        dims = tuple(int(x) for x in args.mesh.split("x"))
-        import os
-
-        os.environ.setdefault(
-            "XLA_FLAGS", f"--xla_force_host_platform_device_count={int(np.prod(dims))}"
-        )
     import jax
     import jax.numpy as jnp
 
@@ -114,6 +107,10 @@ def main() -> None:
     from ..data import DataConfig, FederatedData
     from ..dfl import DFLConfig, DFLTrainer
     from ..models import Batch, build_model
+    from .compile_cache import enable_compile_cache
+    from .mesh import make_local_mesh
+
+    enable_compile_cache()
 
     scenario = None
     codec = ""
@@ -141,14 +138,8 @@ def main() -> None:
     cfg = get_arch(args.arch)
     if args.smoke:
         cfg = cfg.smoke_variant()
-    if args.mesh:
-        dims = tuple(int(x) for x in args.mesh.split("x"))
-        names = ("pod", "data", "model")[-len(dims):]
-        mesh = jax.sharding.Mesh(
-            np.asarray(jax.devices()[: int(np.prod(dims))]).reshape(dims), names
-        )
-    else:
-        mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
+    dims = tuple(int(x) for x in args.mesh.split("x")) if args.mesh else (1, 1)
+    mesh = make_local_mesh(dims, ("pod", "data", "model")[-len(dims):])
 
     model = build_model(cfg)
     dfl = DFLConfig(gossip_mode=args.gossip, gossip_interval=args.gossip_interval,

@@ -363,7 +363,8 @@ class TestJaxCodec:
         env["PYTHONPATH"] = os.path.join(ROOT, "src")
         code = textwrap.dedent("""
             import jax, jax.numpy as jnp
-            mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+            from repro.launch.mesh import make_local_mesh
+            mesh = make_local_mesh((2, 2, 2), ("pod", "data", "model"))
             from repro.configs import get_arch
             from repro.models import Batch, build_model
             from repro.dfl import DFLConfig, DFLTrainer

@@ -28,7 +28,8 @@ class TestWireDtype:
         out = run_devices("""
             import jax, jax.numpy as jnp, numpy as np
             from jax.sharding import PartitionSpec as P, NamedSharding
-            mesh = jax.make_mesh((8, 1), ("data", "model"))
+            from repro.launch.mesh import make_local_mesh
+            mesh = make_local_mesh((8, 1), ("data", "model"))
             from repro.dfl.collectives import GossipPlan, gossip_exchange
             plan = GossipPlan.build(mesh, ("data",))
             w = np.linspace(-3, 7, 8*16).reshape(8, 16).astype(np.float32)
@@ -52,7 +53,8 @@ class TestWireDtype:
         the gossip step and stay local otherwise."""
         out = run_devices("""
             import jax, jax.numpy as jnp, numpy as np
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
+            from repro.launch.mesh import make_local_mesh
+            mesh = make_local_mesh((4, 2), ("data", "model"))
             from repro.configs import get_arch
             from repro.models import Batch, build_model
             from repro.dfl import DFLConfig, DFLTrainer

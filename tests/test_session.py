@@ -20,7 +20,8 @@ def run_devices(code: str, n_devices: int = 8, timeout: int = 520) -> str:
 def test_session_rounds_with_churn():
     out = run_devices("""
         import jax, jax.numpy as jnp, numpy as np
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_local_mesh
+        mesh = make_local_mesh((4, 2), ("data", "model"))
         from repro.configs import get_arch
         from repro.models import Batch, build_model
         from repro.dfl import DFLConfig, DFLTrainer
@@ -66,7 +67,8 @@ def test_noncontiguous_membership_all_buffer_modes():
     out = run_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P, NamedSharding
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_local_mesh
+        mesh = make_local_mesh((4, 2), ("data", "model"))
         from repro.dfl.collectives import gossip_exchange
         from repro.dfl.session import _plan_for_members
         plan = _plan_for_members(mesh, ("data",), {0, 2, 3})  # node 1 masked
@@ -90,7 +92,8 @@ def test_masked_nodes_keep_local_params():
     out = run_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P, NamedSharding
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_local_mesh
+        mesh = make_local_mesh((4, 2), ("data", "model"))
         from repro.dfl.collectives import gossip_exchange
         from repro.dfl.session import _plan_for_members
         plan = _plan_for_members(mesh, ("data",), {0, 1, 2})  # node 3 masked

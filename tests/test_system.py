@@ -32,22 +32,32 @@ class TestGossipCollectives:
         out = run_devices("""
             import jax, jax.numpy as jnp, numpy as np, json
             from jax.sharding import PartitionSpec as P, NamedSharding
-            mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+            from repro.launch.mesh import make_local_mesh
+            mesh = make_local_mesh((2, 2, 2), ("pod", "data", "model"))
             from repro.dfl.collectives import GossipPlan, gossip_exchange
             plan = GossipPlan.build(mesh, ("pod", "data"))
             w_host = np.arange(4*8, dtype=np.float32).reshape(4, 8)
+            # per node (8, 3): segmented splits this leaf along its leading
+            # axis (4 segments of 2 rows); it flattens and pads the (1, 4)
+            # blocks of "w"
+            v_host = np.arange(4*8*3, dtype=np.float32).reshape(4, 8, 3) ** 1.5
             theta = {
               "w": jax.device_put(jnp.asarray(w_host),
                                   NamedSharding(mesh, P(("pod","data"), "model"))),
+              "v": jax.device_put(jnp.asarray(v_host.reshape(32, 3)),
+                                  NamedSharding(mesh, P(("pod","data")))),
               "b": jax.device_put(jnp.arange(4.0), NamedSharding(mesh, P())),
             }
-            specs = {"w": P(("pod","data"), "model"), "b": P()}
-            mean_row = w_host.mean(axis=0)
+            specs = {"w": P(("pod","data"), "model"), "v": P(("pod","data")), "b": P()}
+            want = {"w": np.broadcast_to(w_host.mean(axis=0), (4, 8)),
+                    "v": np.tile(v_host.mean(axis=0), (4, 1)),
+                    "b": np.arange(4.0)}
             res = {}
-            for mode in ("tree_allreduce","dissemination","flooding","allreduce_ref"):
+            for mode in ("tree_allreduce","dissemination","segmented","flooding",
+                         "allreduce_ref"):
                 out = jax.jit(lambda t: gossip_exchange(mode, plan, mesh, t, specs))(theta)
-                res[mode] = bool(np.allclose(np.asarray(out["w"]),
-                                             np.broadcast_to(mean_row,(4,8)), atol=1e-5))
+                res[mode] = all(np.allclose(np.asarray(out[k]), want[k], rtol=1e-6, atol=1e-5)
+                                for k in want)
             print(json.dumps(res))
         """)
         res = json.loads(out.strip().splitlines()[-1])
@@ -57,7 +67,8 @@ class TestGossipCollectives:
         out = run_devices("""
             import jax, jax.numpy as jnp, numpy as np
             from jax.sharding import PartitionSpec as P, NamedSharding
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
+            from repro.launch.mesh import make_local_mesh
+            mesh = make_local_mesh((4, 2), ("data", "model"))
             from repro.dfl.collectives import GossipPlan, gossip_exchange
             plan = GossipPlan.build(mesh, ("data",))
             w = np.arange(4*2, dtype=np.float32).reshape(4, 2)
@@ -78,7 +89,8 @@ class TestDFLTraining:
     def test_loss_decreases_with_gossip(self):
         out = run_devices("""
             import jax, jax.numpy as jnp, numpy as np
-            mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+            from repro.launch.mesh import make_local_mesh
+            mesh = make_local_mesh((2, 2, 2), ("pod", "data", "model"))
             from repro.configs import get_arch
             from repro.models import Batch, build_model
             from repro.dfl import DFLConfig, DFLTrainer
@@ -109,7 +121,8 @@ class TestDFLTraining:
         beyond-paper schedule is numerically equivalent to the paper's."""
         out = run_devices("""
             import jax, jax.numpy as jnp, numpy as np, json
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
+            from repro.launch.mesh import make_local_mesh
+            mesh = make_local_mesh((4, 2), ("data", "model"))
             from repro.configs import get_arch
             from repro.models import Batch, build_model
             from repro.dfl import DFLConfig, DFLTrainer
