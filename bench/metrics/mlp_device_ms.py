@@ -1,0 +1,7 @@
+"""Own device time per training step of the ops under the model's ``mlp``
+scope (forward, remat and backward), in the traced window."""
+import scopes
+
+
+def read(ctx):
+    return scopes.layer_ms_per_step(ctx, (scopes.MLP,))

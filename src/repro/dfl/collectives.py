@@ -49,6 +49,7 @@ from ..core.schedule import (
     decompose_matchings,
     plan_to_perm_steps,
 )
+from ..obs import scopes
 
 PyTree = Any
 
@@ -191,16 +192,19 @@ def _tree_allreduce_body(plan: GossipPlan, theta: PyTree,
 
     ax = _axis_name(plan.node_axes)
     nid = _node_index(plan.node_axes)
+    n_red = plan.n_tree_reduce_steps
     acc = jax.tree.map(lambda t: t.astype(jnp.float32), theta)
-    for step in plan.tree_steps[: plan.n_tree_reduce_steps]:
-        recv = jax.tree.map(lambda t: hop(t, step.perm), acc)
-        acc = jax.tree.map(lambda a, r: a + r.astype(jnp.float32), acc, recv)
+    for i, step in enumerate(plan.tree_steps[:n_red]):
+        with jax.named_scope(scopes.gossip_step(i)):
+            recv = jax.tree.map(lambda t: hop(t, step.perm), acc)
+            acc = jax.tree.map(lambda a, r: a + r.astype(jnp.float32), acc, recv)
     val = acc
-    for step in plan.tree_steps[plan.n_tree_reduce_steps:]:
-        is_recv = jnp.take(jnp.asarray(step.recv_payload >= 0), nid)
-        recv = jax.tree.map(lambda t: hop(t, step.perm), val)
-        val = jax.tree.map(
-            lambda r, v: jnp.where(is_recv, r.astype(jnp.float32), v), recv, val)
+    for i, step in enumerate(plan.tree_steps[n_red:], start=n_red):
+        with jax.named_scope(scopes.gossip_step(i)):
+            is_recv = jnp.take(jnp.asarray(step.recv_payload >= 0), nid)
+            recv = jax.tree.map(lambda t: hop(t, step.perm), val)
+            val = jax.tree.map(
+                lambda r, v: jnp.where(is_recv, r.astype(jnp.float32), v), recv, val)
     # churn masking (dfl.session): nodes with color -1 are outside the healthy
     # subgraph — they keep their local params and neither send nor receive
     if (np.asarray(plan.colors) < 0).any():
@@ -236,19 +240,20 @@ def _apply_perm_steps(steps: Sequence[PermStep], buf: PyTree, ax, nid,
     payload is exact for every shipped codec, so forwarding pays the
     compression error only once — at the original sender).
     """
-    for step in steps:
-        send_idx = jnp.take(jnp.asarray(step.send_payload), nid)
-        recv_idx = jnp.take(jnp.asarray(step.recv_payload), nid)
+    for i, step in enumerate(steps):
+        with jax.named_scope(scopes.gossip_step(i)):
+            send_idx = jnp.take(jnp.asarray(step.send_payload), nid)
+            recv_idx = jnp.take(jnp.asarray(step.recv_payload), nid)
 
-        def one(b):
-            payload = jax.lax.dynamic_index_in_dim(
-                b, jnp.maximum(send_idx, 0), 0, keepdims=False)
-            got = _ppermute_wire(payload, ax, step.perm, codec)
-            updated = jax.lax.dynamic_update_index_in_dim(
-                b, got.astype(b.dtype), jnp.maximum(recv_idx, 0), 0)
-            return jnp.where(recv_idx >= 0, updated, b)
+            def one(b):
+                payload = jax.lax.dynamic_index_in_dim(
+                    b, jnp.maximum(send_idx, 0), 0, keepdims=False)
+                got = _ppermute_wire(payload, ax, step.perm, codec)
+                updated = jax.lax.dynamic_update_index_in_dim(
+                    b, got.astype(b.dtype), jnp.maximum(recv_idx, 0), 0)
+                return jnp.where(recv_idx >= 0, updated, b)
 
-        buf = jax.tree.map(one, buf)
+            buf = jax.tree.map(one, buf)
     return buf
 
 
@@ -356,19 +361,20 @@ def _mixing_body(plan: GossipPlan, theta: PyTree, lam: float = 1.0) -> PyTree:
         return theta
     ax = _axis_name(plan.node_axes)
     nid = _node_index(plan.node_axes)
-    for matching in plan.mixing_matchings:
+    for i, matching in enumerate(plan.mixing_matchings):
         perm = [(u, v) for (u, v) in matching] + [(v, u) for (u, v) in matching]
         members = np.zeros(plan.n_nodes, bool)
         for u, v in matching:
             members[u] = members[v] = True
-        in_match = jnp.take(jnp.asarray(members), nid)
+        with jax.named_scope(scopes.gossip_step(i)):
+            in_match = jnp.take(jnp.asarray(members), nid)
 
-        def one(t):
-            recv = jax.lax.ppermute(t, ax, perm)
-            mixed = (1 - lam / 2) * t.astype(jnp.float32) + (lam / 2) * recv.astype(jnp.float32)
-            return jnp.where(in_match, mixed.astype(t.dtype), t)
+            def one(t):
+                recv = jax.lax.ppermute(t, ax, perm)
+                mixed = (1 - lam / 2) * t.astype(jnp.float32) + (lam / 2) * recv.astype(jnp.float32)
+                return jnp.where(in_match, mixed.astype(t.dtype), t)
 
-        theta = jax.tree.map(one, theta)
+            theta = jax.tree.map(one, theta)
     return theta
 
 
@@ -387,7 +393,8 @@ def _flooding_body(plan: GossipPlan, theta: PyTree, codec=None) -> PyTree:
         allm = jax.lax.all_gather(tw, ax)  # (N, ...)
         return jnp.mean(allm.astype(jnp.float32), axis=0).astype(t.dtype)
 
-    return jax.tree.map(one, theta)
+    with jax.named_scope(scopes.gossip_step(0)):  # one all-gather
+        return jax.tree.map(one, theta)
 
 
 def _allreduce_ref_body(plan: GossipPlan, theta: PyTree) -> PyTree:

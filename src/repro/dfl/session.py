@@ -141,6 +141,7 @@ class DFLSession:
     round_idx: int = 0
     members: Set[int] = field(default_factory=set)
     scenario: Optional[ScenarioSpec] = None
+    steps: int = 0  # steps dispatched by this session (the profiler's step_num)
     _step_fn: Any = None
     _dirty: bool = True
 
@@ -240,16 +241,22 @@ class DFLSession:
         else:
             self._ensure_plan(state_shapes, batch_shapes)
         metrics = None
-        for _ in range(local_steps):
-            if rec.enabled:
-                # the gossip exchange is fused into the jitted step (when
-                # gossip_interval == 1), so the step span covers both; the
-                # args mark it for the trace reader
-                with rec.span("train:step", cat="train", track="train",
-                              round=self.round_idx, gossip=True):
+        every = max(self.trainer.dfl.gossip_interval, 1)
+        # the step gossips when (state.step + 1) % every == 0; read the
+        # device's step count only when a span must say so
+        first = int(state.step) if rec.enabled and every > 1 else 0
+        for i in range(local_steps):
+            with jax.profiler.StepTraceAnnotation("train", step_num=self.steps):
+                if rec.enabled:
+                    # the span times the dispatch (the step runs on after it);
+                    # the gossip exchange is fused into the jitted step
+                    with rec.span("train:dispatch", cat="train", track="train",
+                                  round=self.round_idx,
+                                  gossip=(first + i + 1) % every == 0):
+                        state, metrics = self._step_fn(state, batch)
+                else:
                     state, metrics = self._step_fn(state, batch)
-            else:
-                state, metrics = self._step_fn(state, batch)
+            self.steps += 1
         self.round_idx += 1
         self.rotate_moderator()
         return state, metrics

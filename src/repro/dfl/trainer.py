@@ -25,6 +25,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..compress import make_codec
 from ..configs.base import ArchConfig
 from ..models.model import Batch, Model
+from ..obs import scopes
 from ..optim.optimizers import Optimizer, clip_by_global_norm, global_norm, make_optimizer
 from .collectives import GossipPlan, gossip_exchange
 from .sharding import batch_axes, batch_spec, named, param_spec_tree
@@ -124,7 +125,7 @@ class DFLTrainer:
         model, opt, dfl, plan, mesh = self.model, self.opt, self.dfl, self.plan, self.mesh
         cfg, codec = self.cfg, self.codec
 
-        def step_fn(state: TrainState, batch: Batch, param_specs: PyTree):
+        def grads_of(params: PyTree, batch: Batch) -> Tuple[jax.Array, PyTree]:
             mb = max(int(cfg.microbatches), 1)
             if mb > 1 and batch.tokens.shape[0] % mb == 0:
                 # gradient accumulation: sequential microbatches bound
@@ -136,23 +137,28 @@ class DFLTrainer:
                 micro = Batch(tokens=split(batch.tokens), labels=split(batch.labels),
                               encoder_frames=split(batch.encoder_frames),
                               patch_embeddings=split(batch.patch_embeddings))
-                zero = jax.tree.map(
-                    lambda p: jnp.zeros(p.shape, jnp.float32), state.params)
+                zero = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
 
                 def acc_body(carry, mb_batch):
                     loss_acc, g_acc = carry
-                    l, g = jax.value_and_grad(model.train_loss)(state.params, mb_batch)
+                    l, g = jax.value_and_grad(model.train_loss)(params, mb_batch)
                     g_acc = jax.tree.map(
                         lambda a, x: a + x.astype(jnp.float32) / mb, g_acc, g)
                     return (loss_acc + l / mb, g_acc), None
 
                 (loss, grads), _ = jax.lax.scan(
                     acc_body, (jnp.zeros((), jnp.float32), zero), micro)
-                grads = jax.tree.map(lambda g, p: g.astype(p.dtype), grads, state.params)
-            else:
-                loss, grads = jax.value_and_grad(model.train_loss)(state.params, batch)
-            grads, gnorm = clip_by_global_norm(grads, dfl.max_grad_norm)
-            params, opt_state = opt.update(state.params, grads, state.opt_state, state.step)
+                return loss, jax.tree.map(lambda g, p: g.astype(p.dtype), grads, params)
+            return jax.value_and_grad(model.train_loss)(params, batch)
+
+        def step_fn(state: TrainState, batch: Batch, param_specs: PyTree):
+            with jax.named_scope(scopes.GRADS):
+                loss, grads = grads_of(state.params, batch)
+            with jax.named_scope(scopes.CLIP):
+                grads, gnorm = clip_by_global_norm(grads, dfl.max_grad_norm)
+            with jax.named_scope(scopes.UPDATE):
+                params, opt_state = opt.update(state.params, grads, state.opt_state,
+                                               state.step)
             if "codec_ef" in state.opt_state and "codec_ef" not in opt_state:
                 # optimizers rebuild their state dict; carry the residual over
                 opt_state = dict(opt_state, codec_ef=state.opt_state["codec_ef"])
@@ -169,6 +175,7 @@ class DFLTrainer:
                                        param_specs, wire_dtype=wire,
                                        codec=codec), None
 
+            @scopes.scoped(scopes.GOSSIP)
             def do_gossip(params, opt_state):
                 ef = opt_state.get("codec_ef")
                 if "master" in opt_state:

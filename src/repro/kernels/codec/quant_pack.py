@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ...obs import scopes
+
 
 def _quant_kernel(x_ref, q_ref, s_ref, *, qmax: float):
     x = x_ref[...].astype(jnp.float32)  # (block_c, chunk)
@@ -66,6 +68,7 @@ def quantize_chunks(
             jax.ShapeDtypeStruct((cp, 1), jnp.float32),
         ],
         interpret=interpret,
+        name=scopes.CODEC_QUANTIZE,
     )(xp)
     return codes[:c], scales[:c, 0]
 
@@ -91,5 +94,6 @@ def dequantize_chunks(
         out_specs=pl.BlockSpec((block_c, chunk), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((cp, chunk), jnp.float32),
         interpret=interpret,
+        name=scopes.CODEC_DEQUANTIZE,
     )(qp, sp)
     return out[:c]

@@ -28,6 +28,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from ...obs import scopes
+
 
 def _topk_kernel(x_ref, v_ref, i_ref, *, k: int):
     x = x_ref[...].astype(jnp.float32)  # (block_c, block)
@@ -87,5 +89,6 @@ def topk_select_blocks(
             jax.ShapeDtypeStruct((cp, k), jnp.int32),
         ],
         interpret=interpret,
+        name=scopes.CODEC_TOPK,
     )(xp)
     return vals[:c], idx[:c]

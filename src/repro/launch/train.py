@@ -18,6 +18,11 @@ recompile on every membership change, moderator rotation every round):
   PYTHONPATH=src python -m repro.launch.train --arch smollm-360m --smoke \
       --mesh 1x4x2 --scenario churn_storm   # 8 devices
 
+With ``--profile-dir DIR`` the JAX profiler runs over every step after the
+first (so the compile stays outside it) and writes its trace under DIR: open
+it in XProf or Perfetto to see each step's device ops under the step's named
+scopes (``repro.obs.scopes``) beside the host spans of ``--trace``.
+
 With ``--sweep NAME`` the run is one cell of a registered experiment grid
 (:mod:`repro.scenario.sweep`) — the launcher-array pattern: ``--cell K``
 trains the K-th expanded cell's scenario (one cell per process / SLURM
@@ -60,6 +65,11 @@ def main() -> None:
     ap.add_argument("--trace", default="",
                     help="record an observability trace of the run and write "
                          "Chrome/Perfetto JSON to this path")
+    ap.add_argument("--profile-dir", default="",
+                    help="run the JAX profiler over every step after the first "
+                         "(the compile stays outside) and write its trace here, "
+                         "for XProf or Perfetto: device ops under the step's "
+                         "named scopes, beside the host spans")
     args = ap.parse_args()
 
     if args.trace:
@@ -70,6 +80,9 @@ def main() -> None:
     if args.sweep and args.scenario:
         raise SystemExit("--sweep and --scenario are mutually exclusive: "
                          "a sweep cell *is* the scenario for the run")
+    if args.profile_dir and (args.scenario or args.sweep):
+        raise SystemExit("--profile-dir profiles the plain step loop, not "
+                         "--scenario or --sweep rounds")
     if args.cell >= 0 and not args.sweep:
         raise SystemExit("--cell is an index into --sweep; pass a sweep name "
                          "(see repro.scenario.scenarios.sweep_names())")
@@ -183,22 +196,34 @@ def main() -> None:
     rec = obs.get()
     t0 = time.time()
     for i in range(args.steps):
-        if rec.enabled:
-            with rec.span("train:step", cat="train", track="train", step=i,
-                          gossip=(i + 1) % max(args.gossip_interval, 1) == 0):
+        if i == 1 and args.profile_dir:
+            jax.profiler.start_trace(args.profile_dir)
+        with jax.profiler.StepTraceAnnotation("train", step_num=i):
+            if rec.enabled:
+                with rec.span("train:dispatch", cat="train", track="train", step=i,
+                              gossip=(i + 1) % max(args.gossip_interval, 1) == 0):
+                    state, metrics = step_fn(state, batch)
+            else:
                 state, metrics = step_fn(state, batch)
-        else:
-            state, metrics = step_fn(state, batch)
         batch = make_batch()
         if (i + 1) % args.log_every == 0 or i == 0:
             print(f"step {i+1:5d} loss={float(metrics['loss']):.4f} "
                   f"gnorm={float(metrics['grad_norm']):.3f} "
                   f"({(time.time()-t0)/(i+1):.2f}s/step)")
+        if i == 0:
+            t1 = time.time()  # the first step, compile included, is done: its loss was read
         if args.checkpoint_dir and args.checkpoint_every and (i + 1) % args.checkpoint_every == 0:
             save_pytree(f"{args.checkpoint_dir}/step{i+1:08d}",
                         jax.device_get(state.params),
                         {"step": i + 1, "arch": cfg.name})
-    print(f"done: {args.steps} steps in {time.time()-t0:.1f}s")
+    jax.block_until_ready(state)
+    t_end = time.time()
+    if args.profile_dir and args.steps > 1:
+        jax.profiler.stop_trace()
+        print(f"wrote a profile of steps 2-{args.steps} under {args.profile_dir}")
+    rate = (f", {(args.steps - 1) / (t_end - t1):.4f} steps/s after the first"
+            if args.steps > 1 else "")
+    print(f"done: {args.steps} steps in {t_end - t0:.1f}s{rate}")
     _flush_trace(args.trace)
 
 

@@ -13,6 +13,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..obs import scopes
 from .layers import Params, apply_rope, dense_init, shard_hint
 
 Q_BLOCK = 256  # query-block size for chunked attention
@@ -72,6 +73,7 @@ def _divides(n_heads: int) -> bool:
                 and n_heads % mesh.shape["model"] == 0)
 
 
+@scopes.scoped(scopes.ATTENTION)
 def attention(
     params: Params,
     x: jax.Array,  # (b, s, d)
@@ -165,6 +167,7 @@ def init_kv_cache(
     }
 
 
+@scopes.scoped(scopes.ATTENTION)
 def decode_attention(
     params: Params,
     x: jax.Array,  # (b, 1, d)

@@ -7,6 +7,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import scopes
+
 Params = Dict[str, Any]
 
 VOCAB_PAD_MULTIPLE = 128  # pad embedding rows so vocab shards over "model"
@@ -110,6 +112,7 @@ def init_embedding(key: jax.Array, vocab: int, d_model: int, dtype: Any) -> Para
     return {"table": dense_init(key, (padded_vocab(vocab), d_model), dtype)}
 
 
+@scopes.scoped(scopes.EMBED)
 def embed(params: Params, tokens: jax.Array) -> jax.Array:
     return jnp.take(params["table"], tokens, axis=0)
 
@@ -168,6 +171,7 @@ def init_mlp(key: jax.Array, d_model: int, d_ff: int, dtype: Any) -> Params:
     }
 
 
+@scopes.scoped(scopes.MLP)
 def mlp(params: Params, x: jax.Array) -> jax.Array:
     g = jax.nn.silu(jnp.einsum("...d,df->...f", x, params["wg"]))
     u = jnp.einsum("...d,df->...f", x, params["wi"])

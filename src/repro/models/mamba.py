@@ -21,6 +21,7 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..obs import scopes
 from .layers import Params, dense_init, shard_hint
 
 # ---------------------------------------------------------------------------
@@ -165,6 +166,7 @@ def _mamba1_ssm_inputs(params: Params, xc: jax.Array):
     return dt, Bm, Cm
 
 
+@scopes.scoped(scopes.SSM)
 def mamba1_forward(params: Params, x: jax.Array, d_state: int, dt_rank: int,
                    chunk: int = 64, sequential: bool = False) -> jax.Array:
     """Full-sequence Mamba1 block. x: (b, s, d_model)."""
@@ -206,6 +208,7 @@ def init_mamba1_cache(batch: int, d_inner: int, d_state: int, conv_width: int,
     }
 
 
+@scopes.scoped(scopes.SSM)
 def mamba1_decode(params: Params, x: jax.Array, cache: Dict[str, jax.Array],
                   d_state: int, dt_rank: int) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """One-token step. x: (b, 1, d_model)."""
@@ -261,6 +264,7 @@ def _mamba2_inputs(params: Params, x: jax.Array, conv_cache=None):
     return xc, z, Bm, Cm, dt, new_conv
 
 
+@scopes.scoped(scopes.SSM)
 def mamba2_forward(params: Params, x: jax.Array, d_state: int, head_dim: int = 64,
                    chunk: int = 16, sequential: bool = False) -> jax.Array:
     b, s, _ = x.shape
@@ -304,6 +308,7 @@ def init_mamba2_cache(batch: int, d_inner: int, d_state: int, conv_width: int,
     }
 
 
+@scopes.scoped(scopes.SSM)
 def mamba2_decode(params: Params, x: jax.Array, cache: Dict[str, jax.Array],
                   d_state: int, head_dim: int = 64) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     b = x.shape[0]

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ArchConfig
+from ..obs import scopes
 from . import attention as attn_lib
 from . import mamba as mamba_lib
 from .layers import (
@@ -314,10 +315,12 @@ class Model:
         else:
             raise ValueError(fam)
 
-        x = rms_norm(x, params["final_norm"])
-        logits = logits_from_embedding(params["embed"], x, cfg.vocab, cfg.final_logit_softcap)
-        if fam == "vlm" and prefix_len:
-            logits = logits[:, prefix_len:]
+        with jax.named_scope(scopes.LM_HEAD_LOSS):
+            x = rms_norm(x, params["final_norm"])
+            logits = logits_from_embedding(params["embed"], x, cfg.vocab,
+                                           cfg.final_logit_softcap)
+            if fam == "vlm" and prefix_len:
+                logits = logits[:, prefix_len:]
         return logits, aux
 
     def _forward_hybrid(self, params: Params, x: jax.Array, positions: jax.Array) -> jax.Array:
@@ -397,15 +400,17 @@ class Model:
             return (x, positions), None
 
         (x, _), _ = jax.lax.scan(self._maybe_remat(dec_body), (x, positions), params["blocks"])
-        x = rms_norm(x, params["final_norm"])
-        return logits_from_embedding(params["embed"], x, cfg.vocab)
+        with jax.named_scope(scopes.LM_HEAD_LOSS):
+            x = rms_norm(x, params["final_norm"])
+            return logits_from_embedding(params["embed"], x, cfg.vocab)
 
     # ======================================================================
     # losses
     # ======================================================================
     def train_loss(self, params: Params, batch: Batch) -> jax.Array:
         logits, aux = self.forward(params, batch)
-        return cross_entropy_loss(logits, batch.labels) + MOE_AUX_WEIGHT * aux
+        with jax.named_scope(scopes.LM_HEAD_LOSS):
+            return cross_entropy_loss(logits, batch.labels) + MOE_AUX_WEIGHT * aux
 
     # ======================================================================
     # decode: cache + one-token step

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import scopes
 from .layers import Params, dense_init
 
 
@@ -39,6 +40,7 @@ def init_moe(
 GROUP_SIZE = 256  # tokens per dispatch group
 
 
+@scopes.scoped(scopes.MOE)
 def moe_layer(
     params: Params,
     x: jax.Array,  # (b, s, d)

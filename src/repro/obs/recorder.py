@@ -4,7 +4,10 @@ One :class:`Recorder` collects everything a run wants to expose:
 
 * **spans** — named intervals on named *tracks* (lanes). Wall-clock spans
   come from the :meth:`Recorder.span` context manager (``time.perf_counter``
-  relative to the recorder's origin, so traces start at t=0); virtual-clock
+  relative to the recorder's origin, so traces start at t=0), which also
+  enters ``jax.profiler.TraceAnnotation(name)``: with the JAX profiler on,
+  each such span sits in the profile's host plane on the device trace's
+  clock, beside the device ops it dispatched; virtual-clock
   spans are filed directly with :meth:`Recorder.add_span` using simulator
   timestamps (the discrete-event engine's virtual seconds). Both are plain
   ``(t0, t1)`` seconds — the Chrome-trace exporter does not care which clock
@@ -121,6 +124,9 @@ class Recorder(NullRecorder):
         self.gauges: Dict[str, float] = {}
         self.samples: List[Tuple[str, str, float, float]] = []
         self._origin = time.perf_counter()
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
 
     # -- clocks --------------------------------------------------------------
     def now(self) -> float:
@@ -131,11 +137,13 @@ class Recorder(NullRecorder):
     @contextmanager
     def span(self, name: str, cat: str = "", track: str = "main",
              **args: Any) -> Iterator[None]:  # type: ignore[override]
-        """A wall-clock span around a ``with`` body. Nested spans on one
+        """A wall-clock span around a ``with`` body, mirrored into the JAX
+        profiler's host plane under the same name. Nested spans on one
         track nest by containment in the trace viewer."""
         t0 = self.now()
         try:
-            yield
+            with self._annotation(name):
+                yield
         finally:
             self.spans.append(Span(name, cat, track, t0, self.now(),
                                    args or None))
