@@ -1,23 +1,32 @@
 """GQA attention: full / sliding-window / softcapped, train + cached decode.
 
-Long sequences use a query-block scan so the score matrix is never
-materialized at (seq × seq): per block the footprint is (block × seq), which
-keeps 32k-prefill lowering memory-sane. Decode attends one token against the
-(possibly ring-buffered) KV cache; with a sequence-sharded cache the softmax
-reductions become GSPMD collectives automatically.
+Plain causal self-attention on a TPU (no window, softcap, prefix or external
+K/V, positions equal to the row index, a length the kernel's blocks divide,
+a head_dim the kernel takes)
+runs through JAX's fused Pallas flash-attention kernel, forward and backward,
+which skips the key blocks above the diagonal. Every other case uses a
+query-block scan so the score matrix is never materialized at (seq × seq):
+per block the footprint is (block × seq), which keeps 32k-prefill lowering
+memory-sane. Decode attends one token against the (possibly ring-buffered)
+KV cache; with a sequence-sharded cache the softmax reductions become GSPMD
+collectives automatically.
 """
 from __future__ import annotations
 
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu import flash_attention as tpu_flash
+from jax.sharding import PartitionSpec as P
 
 from ..obs import scopes
-from .layers import Params, apply_rope, dense_init, shard_hint
+from .layers import Params, apply_rope, dense_init, get_mesh_ctx, shard_hint
 
 Q_BLOCK = 256  # query-block size for chunked attention
 NEG_INF = -2.0e38
+FLASH_TILES = (1024, 512, 256, 128)  # the fused kernel's tile sizes, largest first
 
 
 def init_attention(
@@ -32,12 +41,12 @@ def init_attention(
     }
 
 
-def _expand_kv(k: jax.Array, n_heads: int) -> jax.Array:
-    """(b, s, kv, hd) -> (b, s, H, hd) by repeating groups."""
-    n_kv = k.shape[-2]
+def _expand_kv(k: jax.Array, n_heads: int, axis: int = -2) -> jax.Array:
+    """(b, s, kv, hd) -> (b, s, H, hd) by repeating groups (heads on ``axis``)."""
+    n_kv = k.shape[axis]
     if n_kv == n_heads:
         return k
-    return jnp.repeat(k, n_heads // n_kv, axis=-2)
+    return jnp.repeat(k, n_heads // n_kv, axis=axis)
 
 
 def _softcap(scores: jax.Array, cap: float) -> jax.Array:
@@ -66,11 +75,73 @@ def _attend_block(
 
 
 def _divides(n_heads: int) -> bool:
-    from .layers import get_mesh_ctx
-
     mesh, _ = get_mesh_ctx()
     return bool(mesh is not None and "model" in mesh.shape
                 and n_heads % mesh.shape["model"] == 0)
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _tile(s: int, cap: int) -> int:
+    return next(b for b in FLASH_TILES if b <= cap and s % b == 0)
+
+
+def flash_block_sizes(s: int, fwd: Tuple[int, int] = (1024, 1024),
+                      dkv: Tuple[int, int] = (512, 1024),
+                      dq: Tuple[int, int] = (1024, 256)) -> tpu_flash.BlockSizes:
+    """The fused kernel's tiles for sequence length ``s``: for the forward,
+    dK/dV and dQ kernels, a (query, key) tile each, every side the largest of
+    :data:`FLASH_TILES` up to its cap that divides ``s``. The caps, forward
+    1024 by 1024, dK/dV 512 by 1024 and dQ 1024 by 256 (the dQ kernel's f32
+    row statistic is broadcast to its key tile in HBM), were set from a sweep
+    on a v5e at the train cells' shapes (``tools/flash_sweep.py``; PERF.md)."""
+    (fq, fk), (dkv_q, dkv_k), (dq_q, dq_k) = (
+        tuple(_tile(s, cap) for cap in caps) for caps in (fwd, dkv, dq))
+    return tpu_flash.BlockSizes(
+        block_q=fq, block_k_major=fk, block_k=fk, block_b=1,
+        block_q_major_dkv=dkv_q, block_q_dkv=dkv_q, block_k_major_dkv=dkv_k, block_k_dkv=dkv_k,
+        block_q_dq=dq_q, block_k_major_dq=dq_k, block_k_dq=dq_k,
+    )
+
+
+def _flash_causal(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
+    """Causal attention of head-major (b, H, s, hd) q, k, v by the fused
+    kernel. Under a mesh the kernel runs per shard inside ``shard_map`` (batch
+    over the batch axes, heads over ``model`` where they divide), so GSPMD
+    never has to partition the kernel's custom call."""
+    b, n_heads, s, hd = q.shape
+
+    def fn(q_, k_, v_):
+        return tpu_flash.flash_attention(q_, k_, v_, causal=True, sm_scale=hd ** -0.5,
+                                         block_sizes=flash_block_sizes(s))
+
+    mesh, batch_axes = get_mesh_ctx()
+    with jax.named_scope(scopes.ATTENTION_FLASH):
+        if mesh is None:
+            return fn(q, k, v)
+        n_batch = math.prod(mesh.shape[a] for a in batch_axes)
+        spec = P(batch_axes if batch_axes and b % n_batch == 0 else None,
+                 "model" if _divides(n_heads) else None, None, None)
+        return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+                             check_vma=False)(q, k, v)
+
+
+def _fused_attention(params: Params, x: jax.Array, positions: jax.Array,
+                     rope_theta: float, use_rope: bool) -> jax.Array:
+    """Plain causal self-attention, head-major from the projections on."""
+    n_heads = params["wq"].shape[1]
+    q = jnp.einsum("bsd,dhk->bhsk", x, params["wq"])
+    k = jnp.einsum("bsd,dhk->bhsk", x, params["wk"])
+    v = jnp.einsum("bsd,dhk->bhsk", x, params["wv"])
+    if use_rope:
+        q = apply_rope(q, positions, rope_theta, heads_first=True)
+        k = apply_rope(k, positions, rope_theta, heads_first=True)
+    k = _expand_kv(k, n_heads, axis=1)
+    v = _expand_kv(v, n_heads, axis=1)
+    out = _flash_causal(q, k, v)
+    return jnp.einsum("bhsk,hkd->bsd", out, params["wo"])
 
 
 @scopes.scoped(scopes.ATTENTION)
@@ -87,9 +158,22 @@ def attention(
     kv_override: Optional[Tuple[jax.Array, jax.Array]] = None,  # cross-attention
     kv_positions: Optional[jax.Array] = None,
     prefix_len: int = 0,  # vlm: first `prefix_len` positions attend bidirectionally
+    positions_are_rows: bool = False,
 ) -> jax.Array:
-    """Full-sequence attention (train / prefill / encoder / cross)."""
+    """Full-sequence attention (train / prefill / encoder / cross).
+
+    ``positions_are_rows`` is the caller's word that ``positions`` is
+    ``arange(s)`` on every row: only then may the fused kernel, which masks
+    by index, take a causal self-attention call.
+    """
     b, s, _ = x.shape
+    hd = params["wq"].shape[-1]
+    # the kernel masks causally by row and column index, and by nothing else;
+    # it takes a head_dim of at most 128 or a multiple of 128
+    if (causal and positions_are_rows and kv_override is None and prefix_len == 0
+            and sliding_window == 0 and softcap == 0 and s % FLASH_TILES[-1] == 0
+            and (hd <= 128 or hd % 128 == 0) and _on_tpu()):
+        return _fused_attention(params, x, positions, rope_theta, use_rope)
     n_heads = params["wq"].shape[1]
     q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])
     if kv_override is None:

@@ -139,8 +139,10 @@ def rope_frequencies(head_dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10_000.0) -> jax.Array:
-    """x: (..., seq, heads, head_dim); positions: (..., seq).
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10_000.0, *,
+               heads_first: bool = False) -> jax.Array:
+    """x: (..., seq, heads, head_dim), or (..., heads, seq, head_dim) with
+    ``heads_first``; positions: (..., seq).
 
     Interleaved-pair convention (rotates (x[2i], x[2i+1]) pairs) rather than
     rotate-half: adjacent pairs stay inside a "model"-axis shard when head_dim
@@ -148,7 +150,7 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10_000.0) -> j
     """
     freqs = rope_frequencies(x.shape[-1], theta)  # (hd/2,)
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # (..., seq, hd/2)
-    angles = angles[..., :, None, :]  # broadcast over heads
+    angles = angles[..., None, :, :] if heads_first else angles[..., :, None, :]  # over heads
     cos, sin = jnp.cos(angles), jnp.sin(angles)
     xf = x.astype(jnp.float32)
     pairs = xf.reshape(*xf.shape[:-1], xf.shape[-1] // 2, 2)

@@ -235,7 +235,8 @@ class Model:
             x, positions = carry
             h = attn_lib.attention(
                 block["attn"], rms_norm(x, block["ln1"]), positions,
-                causal=True, prefix_len=prefix_len, **self._attn_kwargs(window),
+                causal=True, prefix_len=prefix_len, positions_are_rows=True,
+                **self._attn_kwargs(window),
             )
             x = x + h
             x = x + mlp(block["mlp"], rms_norm(x, block["ln2"]))
@@ -285,7 +286,7 @@ class Model:
                 x, positions, aux = carry
                 h = attn_lib.attention(
                     block["attn"], rms_norm(x, block["ln1"]), positions,
-                    causal=True, **self._attn_kwargs(window),
+                    causal=True, positions_are_rows=True, **self._attn_kwargs(window),
                 )
                 x = x + h
                 y, a = moe_layer(block["moe"], rms_norm(x, block["ln2"]), cfg.top_k,
@@ -348,7 +349,8 @@ class Model:
             x, _ = jax.lax.scan(inner, x, mblocks)
             # shared attention block (weights reused across super-blocks)
             h = attn_lib.attention(shared["attn"], rms_norm(x, shared["ln1"]), positions,
-                                   causal=True, **self._attn_kwargs(0))
+                                   causal=True, positions_are_rows=True,
+                                   **self._attn_kwargs(0))
             x = x + h
             x = x + mlp(shared["mlp"], rms_norm(x, shared["ln2"]))
             return (self._shard_acts(x), positions), None
@@ -387,7 +389,8 @@ class Model:
         def dec_body(carry, block):
             x, positions = carry
             h = attn_lib.attention(block["attn"], rms_norm(x, block["ln1"]), positions,
-                                   causal=True, rope_theta=cfg.rope_theta)
+                                   causal=True, rope_theta=cfg.rope_theta,
+                                   positions_are_rows=True)
             x = x + h
             # cross attention: K/V from encoder output, no rope
             kc = jnp.einsum("bsd,dhk->bshk", enc, block["cross"]["wk"])

@@ -12,7 +12,9 @@ Model layers (inside the shared functions, so every family carries them):
 
 * :data:`EMBED` — the token embedding lookup;
 * :data:`ATTENTION` — q/k/v/o projections, RoPE, KV expansion and the
-  query-block loop (train, prefill and decode);
+  query-block loop (train, prefill and decode), with :data:`ATTENTION_FLASH`
+  inside it around the fused flash-attention kernel where that runs instead
+  of the loop (``models/attention.py``);
 * :data:`MLP` — the SwiGLU MLP;
 * :data:`MOE` — the expert block (router, dispatch, experts, combine);
 * :data:`SSM` — the Mamba block;
@@ -35,6 +37,7 @@ import jax
 
 EMBED = "embed"
 ATTENTION = "attention"
+ATTENTION_FLASH = "attention.flash"
 MLP = "mlp"
 MOE = "moe"
 SSM = "ssm"

@@ -112,3 +112,93 @@ def test_gossip_exchange_compiles_on_2x2(mode, codec, topo, kernels_for_tpu):
     hlo = out.as_text()
     assert "collective-permute" in hlo
     assert ("tpu_custom_call" in hlo) == bool(codec)
+
+
+@pytest.fixture
+def attention_backend(monkeypatch):
+    """Set which attention path ``models.attention`` takes: its backend check
+    sees the CPU here, and the compiles below target the described chip."""
+    from repro.models import attention as attn_lib
+    from repro.models.layers import get_mesh_ctx, set_mesh_ctx
+
+    was = get_mesh_ctx()
+
+    def use(fused: bool, mesh=None, batch_axes=()):
+        jax.clear_caches()
+        monkeypatch.setattr(attn_lib, "_on_tpu", lambda: fused)
+        set_mesh_ctx(mesh, batch_axes)
+
+    yield use
+    set_mesh_ctx(*was)
+    jax.clear_caches()
+
+
+def _attention_layer_grad(arch, b, s, sharding_of):
+    """``value_and_grad`` of one attention layer of ``arch`` at its widths,
+    rematerialised as the model's layers are, compiled; ``sharding_of(rank,
+    is_activation)`` places each argument."""
+    from repro.configs import get_arch
+    from repro.models import attention as attn_lib
+
+    cfg = get_arch(arch)
+    params = jax.eval_shape(lambda: attn_lib.init_attention(
+        jax.random.PRNGKey(0), cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim,
+        jnp.bfloat16))
+    params = jax.tree.map(lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype,
+                                                         sharding=sharding_of(p.ndim, False)),
+                          params)
+    x = jax.ShapeDtypeStruct((b, s, cfg.d_model), jnp.bfloat16, sharding=sharding_of(3, True))
+
+    def loss(p, x_):
+        pos = jnp.broadcast_to(jnp.arange(s), (b, s))
+        out = attn_lib.attention(p, x_, pos, rope_theta=cfg.rope_theta,
+                                 positions_are_rows=True)
+        return jnp.sum(out.astype(jnp.float32))
+
+    return _compile(jax.value_and_grad(jax.checkpoint(loss), argnums=(0, 1)), params, x)
+
+
+def test_fused_attention_step_at_smollm_width(one_chip, attention_backend):
+    """The loss gradient of a two-layer smollm-width model at 8 x 2048: the
+    fused path puts the kernel's forward, remat forward, dK/dV and dQ in, and
+    holds no more temporaries than the scan. (One layer alone holds more,
+    726 against 666 MiB: the kernel's backward takes its f32 row statistics
+    broadcast to 128 lanes. In the step the f32 logits set the peak.)"""
+    from repro.configs import get_arch
+    from repro.models import Batch, build_model
+
+    model = build_model(get_arch("smollm-360m").replace(n_layers=2))
+    params = jax.tree.map(lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one_chip),
+                          jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    tok = jax.ShapeDtypeStruct((8, 2048), jnp.int32, sharding=one_chip)
+    batch = Batch(tokens=tok, labels=tok)
+    compiled = {}
+    for fused in (False, True):
+        attention_backend(fused=fused)
+        compiled[fused] = _compile(jax.value_and_grad(model.train_loss), params, batch)
+    assert "tpu_custom_call" not in compiled[False].as_text()
+    assert compiled[True].as_text().count("tpu_custom_call") == 4
+    assert (compiled[True].memory_analysis().temp_size_in_bytes
+            <= compiled[False].memory_analysis().temp_size_in_bytes)
+
+
+@pytest.mark.parametrize("arch,b,s", [("smollm-360m", 8, 2048), ("granite-3-2b", 4, 4096)])
+def test_fused_attention_compiles_on_2x2(arch, b, s, topo, attention_backend):
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    attention_backend(fused=True, mesh=mesh, batch_axes=("data",))
+    hlo = _attention_layer_grad(
+        arch, b, s,
+        lambda rank, batch: NamedSharding(mesh, P("data") if batch else P())).as_text()
+    # forward, remat forward, dK/dV and dQ
+    assert hlo.count("tpu_custom_call") == 4
+
+
+@pytest.mark.parametrize("arch,fused", [("zamba2-7b", True), ("stablelm-12b", False)])
+def test_attention_head_dims_compile(arch, fused, one_chip, attention_backend):
+    """zamba2's head_dim 112 takes the kernel; stablelm-12b's 160, which the
+    kernel refuses once its key tile is shorter than the sequence, keeps the
+    scan and still compiles at 2048."""
+    attention_backend(fused=True)
+    hlo = _attention_layer_grad(arch, 1, 2048, lambda rank, batch: one_chip).as_text()
+    assert hlo.count("tpu_custom_call") == (4 if fused else 0)
