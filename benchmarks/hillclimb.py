@@ -71,12 +71,9 @@ HILLCLIMBS = {
     "zamba2": {
         "arch": "zamba2-7b", "shape": "train_4k", "multi_pod": False,
         "variants": [
-            ("baseline_assoc_scan", dict()),
-            ("sequential_scan",
-             dict(arch_overrides=dict(ssm_sequential_scan=True))),
-            ("sequential_scan+wire_bf16",
-             dict(arch_overrides=dict(ssm_sequential_scan=True),
-                  dfl_overrides=dict(wire_dtype="bfloat16"))),
+            # Mamba2's chunked SSD has no sequential variant
+            ("baseline_ssd", dict()),
+            ("wire_bf16", dict(dfl_overrides=dict(wire_dtype="bfloat16"))),
         ],
     },
     "arctic": {

@@ -24,7 +24,7 @@ class ArchConfig:
     """One architecture. Every field that shapes parameters lives here."""
 
     name: str
-    family: str  # dense | moe | ssm | hybrid | audio | vlm
+    family: str  # dense | moe | ssm | hybrid | interleaved | audio | vlm
     source: str  # citation from the assignment table
 
     n_layers: int = 2
@@ -45,6 +45,7 @@ class ArchConfig:
     attn_logit_softcap: float = 0.0  # gemma2: 50.0
     final_logit_softcap: float = 0.0  # gemma2: 30.0
     rope_theta: float = 10_000.0
+    use_rope: bool = True  # False: no positional embedding (NoPE)
 
     # MoE
     n_experts: int = 0
@@ -58,9 +59,22 @@ class ArchConfig:
     ssm_version: int = 0  # 1 = mamba1, 2 = mamba2
     d_inner_mult: int = 2
     conv_width: int = 4
-    ssm_sequential_scan: bool = False  # kernel-style scan (vs associative)
+    ssm_groups: int = 1  # mamba2: groups of heads sharing one B and C
+    ssm_sequential_scan: bool = False  # mamba1: kernel-style scan (vs associative)
     attn_every: int = 0  # hybrid: one attention block every k layers (zamba2)
     shared_attn: bool = False  # zamba2 shares the attention block weights
+    # interleaved: one period of the layer stack, "M" a Mamba2 layer and "A"
+    # an attention layer, each followed by its own MLP (granite-4.0-h)
+    layer_pattern: str = ""
+
+    # muP multipliers (granite): the embedding's output times
+    # ``embedding_multiplier``, each residual branch times
+    # ``residual_multiplier``, attention scores times ``attention_multiplier``
+    # (0: ``head_dim ** -0.5``), logits over ``logits_scaling``
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 1.0
 
     # modality frontends (STUBS per assignment: precomputed embeddings)
     is_encoder_decoder: bool = False  # whisper
@@ -120,6 +134,10 @@ class ArchConfig:
             if self.attn_free:
                 total += self._mamba_params()
                 continue
+            if self.family == "interleaved":
+                kind = self.layer_pattern[layer % len(self.layer_pattern)]
+                total += (self._mamba2_params() if kind == "M" else attn) + mlp + 2 * d
+                continue
             if self.family == "hybrid":
                 if self.attn_every and (layer + 1) % self.attn_every == 0:
                     if not (self.shared_attn and layer + 1 > self.attn_every):
@@ -154,9 +172,13 @@ class ArchConfig:
         )
 
     def _mamba2_params(self) -> int:
-        d, di, n = self.d_model, self.d_inner, self.ssm_state
+        d, di = self.d_model, self.d_inner
         nheads = max(1, di // 64)
-        return d * (2 * di + 2 * n + nheads) + di * self.conv_width + di * d + 2 * nheads
+        conv = di + 2 * self.ssm_groups * self.ssm_state  # x, B and C
+        return (d * (di + conv + nheads)  # in_proj: z, xBC, dt
+                + conv * (self.conv_width + 1)  # conv and its bias
+                + di * d  # out_proj
+                + 3 * nheads + di)  # A_log, dt_bias, D; gated norm
 
     def active_param_count(self) -> int:
         """MoE: params touched per token (6·N_active·D model-FLOPs basis)."""
@@ -191,8 +213,10 @@ class ArchConfig:
                 kw.update(dense_ff=256)
         if self.family == "hybrid":
             kw.update(attn_every=2, d_model=256, ssm_state=16)
-        if self.attn_free or self.family == "hybrid":
+        if self.attn_free or self.family in ("hybrid", "interleaved"):
             kw.update(ssm_state=16)
+        if self.family == "interleaved":
+            kw.update(n_layers=len(self.layer_pattern))
         if self.is_encoder_decoder:
             kw.update(n_encoder_layers=2, n_frames=64)
         if self.n_patches:
@@ -296,6 +320,7 @@ def _ensure_loaded() -> None:
         falcon_mamba_7b,
         gemma2_2b,
         granite_3_2b,
+        granite_4_h_micro,
         paligemma_3b,
         qwen3_moe_30b_a3b,
         smollm_360m,

@@ -14,6 +14,7 @@ ZAMBA2_7B = register(ArchConfig(
     vocab=32000,
     ssm_state=64,
     ssm_version=2,
+    ssm_groups=2,
     attn_every=6,      # one (shared) attention block every 6 layers
     shared_attn=True,  # zamba2 reuses the same attention block weights
     optimizer_dtype="bfloat16",

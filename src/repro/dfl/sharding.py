@@ -98,9 +98,9 @@ def param_spec_tree(cfg: ArchConfig, params: Any, mesh: Mesh) -> Any:
             trail = (e_ax, m if _div(leaf.shape[-2], mesh, m) else None, None)
         elif name == "router":
             trail = (None, None)
-        elif name in ("wx", "wz"):  # (d, di)
+        elif name in ("wx", "wz", "wxBC"):  # (d, di), mamba2's xBC (d, di + 2 G N)
             trail = (None, m if _div(leaf.shape[-1], mesh, m) else None)
-        elif name == "conv_w":  # (w, di)
+        elif name == "conv_w":  # (w, di), mamba2 (w, di + 2 G N)
             trail = (None, m if _div(leaf.shape[-1], mesh, m) else None)
         elif name in ("wdt_in",):  # (di, r)
             trail = (m if _div(leaf.shape[-2], mesh, m) else None, None)

@@ -61,8 +61,8 @@ def _attend_block(
     v: jax.Array,  # (b, s, H, hd)
     mask: jax.Array,  # (b, qb, s) or (1, qb, s) boolean
     softcap: float,
+    scale: float,
 ) -> jax.Array:
-    scale = q.shape[-1] ** -0.5
     scores = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32)) * scale
     # heads over "model" when divisible (Megatron TP); otherwise attention is
     # replicated within the node (scores keep whatever q/k/v carry)
@@ -106,15 +106,16 @@ def flash_block_sizes(s: int, fwd: Tuple[int, int] = (1024, 1024),
     )
 
 
-def _flash_causal(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
-    """Causal attention of head-major (b, H, s, hd) q, k, v by the fused
-    kernel. Under a mesh the kernel runs per shard inside ``shard_map`` (batch
-    over the batch axes, heads over ``model`` where they divide), so GSPMD
-    never has to partition the kernel's custom call."""
-    b, n_heads, s, hd = q.shape
+def _flash_causal(q: jax.Array, k: jax.Array, v: jax.Array, scale: float) -> jax.Array:
+    """Causal attention of head-major (b, H, s, hd) q, k, v, scores scaled by
+    ``scale``, by the fused kernel. Under a mesh the kernel runs per shard
+    inside ``shard_map`` (batch over the batch axes, heads over ``model``
+    where they divide), so GSPMD never has to partition the kernel's custom
+    call."""
+    b, n_heads, s, _ = q.shape
 
     def fn(q_, k_, v_):
-        return tpu_flash.flash_attention(q_, k_, v_, causal=True, sm_scale=hd ** -0.5,
+        return tpu_flash.flash_attention(q_, k_, v_, causal=True, sm_scale=scale,
                                          block_sizes=flash_block_sizes(s))
 
     mesh, batch_axes = get_mesh_ctx()
@@ -129,7 +130,7 @@ def _flash_causal(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
 
 
 def _fused_attention(params: Params, x: jax.Array, positions: jax.Array,
-                     rope_theta: float, use_rope: bool) -> jax.Array:
+                     rope_theta: float, use_rope: bool, scale: float) -> jax.Array:
     """Plain causal self-attention, head-major from the projections on."""
     n_heads = params["wq"].shape[1]
     q = jnp.einsum("bsd,dhk->bhsk", x, params["wq"])
@@ -140,7 +141,7 @@ def _fused_attention(params: Params, x: jax.Array, positions: jax.Array,
         k = apply_rope(k, positions, rope_theta, heads_first=True)
     k = _expand_kv(k, n_heads, axis=1)
     v = _expand_kv(v, n_heads, axis=1)
-    out = _flash_causal(q, k, v)
+    out = _flash_causal(q, k, v, scale)
     return jnp.einsum("bhsk,hkd->bsd", out, params["wo"])
 
 
@@ -159,21 +160,24 @@ def attention(
     kv_positions: Optional[jax.Array] = None,
     prefix_len: int = 0,  # vlm: first `prefix_len` positions attend bidirectionally
     positions_are_rows: bool = False,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Full-sequence attention (train / prefill / encoder / cross).
 
     ``positions_are_rows`` is the caller's word that ``positions`` is
     ``arange(s)`` on every row: only then may the fused kernel, which masks
-    by index, take a causal self-attention call.
+    by index, take a causal self-attention call. Scores are scaled by
+    ``scale``, ``head_dim ** -0.5`` where it is None.
     """
     b, s, _ = x.shape
     hd = params["wq"].shape[-1]
+    scale = hd ** -0.5 if scale is None else scale
     # the kernel masks causally by row and column index, and by nothing else;
     # it takes a head_dim of at most 128 or a multiple of 128
     if (causal and positions_are_rows and kv_override is None and prefix_len == 0
             and sliding_window == 0 and softcap == 0 and s % FLASH_TILES[-1] == 0
             and (hd <= 128 or hd % 128 == 0) and _on_tpu()):
-        return _fused_attention(params, x, positions, rope_theta, use_rope)
+        return _fused_attention(params, x, positions, rope_theta, use_rope, scale)
     n_heads = params["wq"].shape[1]
     q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])
     if kv_override is None:
@@ -219,7 +223,7 @@ def attention(
     while s % qblk:
         qblk -= 1
     if s <= qblk or qblk < 32:
-        out = _attend_block(q, k, v, mask_for(positions), softcap)
+        out = _attend_block(q, k, v, mask_for(positions), softcap, scale)
     else:
         nb = s // qblk
         qb = q.reshape(b, nb, qblk, n_heads, -1).transpose(1, 0, 2, 3, 4)
@@ -230,7 +234,7 @@ def attention(
         @jax.checkpoint
         def body(_, qp):
             qi, pi = qp
-            return None, _attend_block(qi, k, v, mask_for(pi), softcap)
+            return None, _attend_block(qi, k, v, mask_for(pi), softcap, scale)
 
         _, ob = jax.lax.scan(body, None, (qb, pb))
         out = ob.transpose(1, 0, 2, 3, 4).reshape(b, s, n_heads, -1)
@@ -261,11 +265,14 @@ def decode_attention(
     sliding_window: int = 0,
     softcap: float = 0.0,
     rope_theta: float = 10_000.0,
+    use_rope: bool = True,
+    scale: Optional[float] = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """One-token decode against a (ring-buffered when windowed) KV cache.
 
     The cache stores *rotated* keys, so softmax over cache slots is
-    permutation-invariant and a ring buffer needs no unrotation.
+    permutation-invariant and a ring buffer needs no unrotation. Scores are
+    scaled by ``scale``, ``head_dim ** -0.5`` where it is None.
     """
     b = x.shape[0]
     n_heads = params["wq"].shape[1]
@@ -273,8 +280,9 @@ def decode_attention(
     q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])
     k_new = jnp.einsum("bsd,dhk->bshk", x, params["wk"])
     v_new = jnp.einsum("bsd,dhk->bshk", x, params["wv"])
-    q = apply_rope(q, position[:, None], rope_theta)
-    k_new = apply_rope(k_new, position[:, None], rope_theta)
+    if use_rope:
+        q = apply_rope(q, position[:, None], rope_theta)
+        k_new = apply_rope(k_new, position[:, None], rope_theta)
 
     slot = position % cache_len if sliding_window > 0 else position
     onehot = jax.nn.one_hot(slot, cache_len, dtype=cache["k"].dtype)  # (b, L)
@@ -284,7 +292,7 @@ def decode_attention(
 
     kh = _expand_kv(k, n_heads)
     vh = _expand_kv(v, n_heads)
-    scale = q.shape[-1] ** -0.5
+    scale = q.shape[-1] ** -0.5 if scale is None else scale
     scores = jnp.einsum("bqhk,blhk->bhql", q.astype(jnp.float32), kh.astype(jnp.float32)) * scale
     scores = _softcap(scores, softcap)
     idx = jnp.arange(cache_len)

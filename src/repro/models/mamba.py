@@ -1,17 +1,21 @@
-"""Mamba1 (S6) and Mamba2-style blocks: chunked selective scan + decode step.
+"""Mamba1 (S6) and Mamba2 blocks: full-sequence scans and a decode step.
 
-The naive selective scan materializes (batch, seq, d_inner, state) — tens of
-GB at 7B scale — so the sequence is processed in chunks: an outer `lax.scan`
-carries the (batch, d_inner, state) SSM state across chunks while an inner
-`associative_scan` parallelizes within the chunk; each chunk body is
+Mamba1's naive selective scan materializes (batch, seq, d_inner, state) —
+tens of GB at 7B scale — so the sequence is processed in chunks: an outer
+`lax.scan` carries the (batch, d_inner, state) SSM state across chunks while
+an inner `associative_scan` parallelizes within the chunk; each chunk body is
 `jax.checkpoint`ed so backward recomputes instead of storing. This mirrors
 the memory discipline of the CUDA kernel the paper's ecosystem uses, adapted
 to XLA/TPU (and re-expressed as a Pallas kernel in kernels/scan/).
 
-Projections are kept as separate weights (wz/wx/wB/wC/wdt) rather than one
-fused in_proj: fused layouts would have to be split at boundaries that do not
-align with "model"-axis shards, forcing GSPMD re-gathers. Separate weights
-shard cleanly: d_inner over "model", the small B/C/dt heads replicated.
+Mamba2's decay is one scalar per head, so its scan is computed by the
+state-space duality (:func:`ssd`): per chunk, matrix products on the MXU, and
+a sequential pass over the chunks' states alone.
+
+Projections are kept as separate weights rather than one fused in_proj:
+fused layouts would have to be split at boundaries that do not align with
+"model"-axis shards, forcing GSPMD re-gathers. Separate weights shard
+cleanly: d_inner over "model", the small B/C/dt heads replicated.
 """
 from __future__ import annotations
 
@@ -20,6 +24,7 @@ from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..obs import scopes
 from .layers import Params, dense_init, shard_hint
@@ -229,100 +234,158 @@ def mamba1_decode(params: Params, x: jax.Array, cache: Dict[str, jax.Array],
 
 
 # ---------------------------------------------------------------------------
-# Mamba2-style block (zamba2): scalar decay per head, SSD-lite
+# Mamba2 (granite-4.0-h, zamba2): scalar decay per head, chunked SSD
 # ---------------------------------------------------------------------------
 
+MAMBA2_HEAD_DIM = 64
+SSD_CHUNK = 256  # the published chunk of both models
 
-def init_mamba2(key: jax.Array, d_model: int, d_inner: int, d_state: int,
-                conv_width: int, dtype: Any, head_dim: int = 64) -> Params:
-    ks = jax.random.split(key, 6)
+
+def init_mamba2(key: jax.Array, d_model: int, d_inner: int, d_state: int, n_groups: int,
+                conv_width: int, dtype: Any, head_dim: int = MAMBA2_HEAD_DIM) -> Params:
+    """The published Mamba2 mixer, its in_proj kept as three matrices: z,
+    xBC (x, then each group's B, then each group's C) and dt. Head ``h``
+    decays by ``A = -h - 1``; dt_bias spreads softplus(dt_bias) log-evenly
+    over [1e-3, 1e-1] across the heads, the published range."""
     n_heads = d_inner // head_dim
+    conv_dim = d_inner + 2 * n_groups * d_state
+    kz, kx, kdt, kc, ko = jax.random.split(key, 5)
+    dt = np.exp(np.linspace(np.log(1e-3), np.log(1e-1), n_heads))
     return {
-        "wx": dense_init(ks[0], (d_model, d_inner), dtype),
-        "wz": dense_init(ks[1], (d_model, d_inner), dtype),
-        "wB": dense_init(ks[2], (d_model, d_state), dtype),
-        "wC": dense_init(ks[3], (d_model, d_state), dtype),
-        "wdt": dense_init(ks[4], (d_model, n_heads), dtype),
-        "conv_w": dense_init(ks[5], (conv_width, d_inner), dtype, scale=0.5),
-        "A_log": jnp.zeros((n_heads,), jnp.float32),
-        "dt_bias": jnp.zeros((n_heads,), jnp.float32),
+        "wz": dense_init(kz, (d_model, d_inner), dtype),
+        "wxBC": dense_init(kx, (d_model, conv_dim), dtype),
+        "wdt": dense_init(kdt, (d_model, n_heads), dtype),
+        "conv_w": dense_init(kc, (conv_width, conv_dim), dtype, scale=0.5),
+        "conv_b": jnp.zeros((conv_dim,), jnp.float32),
+        "A_log": jnp.asarray(np.log(np.arange(1, n_heads + 1)), jnp.float32),
+        "dt_bias": jnp.asarray(dt + np.log(-np.expm1(-dt)), jnp.float32),  # softplus^-1(dt)
         "D": jnp.ones((n_heads,), jnp.float32),
-        "out_proj": dense_init(jax.random.fold_in(key, 7), (d_inner, d_model), dtype),
+        "norm": jnp.zeros((d_inner,), jnp.float32),
+        "out_proj": dense_init(ko, (d_inner, d_model), dtype),
     }
 
 
-def _mamba2_inputs(params: Params, x: jax.Array, conv_cache=None):
-    xi = jnp.einsum("bsd,dk->bsk", x, params["wx"])
-    xc, new_conv = _causal_conv(xi, params["conv_w"], conv_cache)
-    xc = jax.nn.silu(xc)
-    z = jnp.einsum("bsd,dk->bsk", x, params["wz"])
-    Bm = jnp.einsum("bsd,dn->bsn", x, params["wB"]).astype(jnp.float32)
-    Cm = jnp.einsum("bsd,dn->bsn", x, params["wC"]).astype(jnp.float32)
-    dt = jax.nn.softplus(
-        jnp.einsum("bsd,dh->bsh", x, params["wdt"]).astype(jnp.float32) + params["dt_bias"]
-    )  # (b, s, h)
-    return xc, z, Bm, Cm, dt, new_conv
+def _mamba2_in(params: Params, u: jax.Array, n_groups: int, conv_cache=None):
+    """Projections and conv of the normed input ``u`` (b, s, d): the gate z,
+    the heads' x (b, s, H, P), each group's B and C (b, s, G, N) in ``u``'s
+    dtype, the step dt (b, s, H) in f32, and the conv's new state."""
+    b, s, _ = u.shape
+    n_heads = params["A_log"].shape[0]
+    d_inner = params["out_proj"].shape[0]
+    z = jnp.einsum("bsd,dk->bsk", u, params["wz"])
+    xBC = jnp.einsum("bsd,dk->bsk", u, params["wxBC"])
+    dt = jax.nn.softplus(jnp.einsum("bsd,dh->bsh", u, params["wdt"]).astype(jnp.float32)
+                         + params["dt_bias"])
+    xBC, new_conv = _causal_conv(xBC.astype(jnp.float32),
+                                 params["conv_w"].astype(jnp.float32), conv_cache)
+    xBC = jax.nn.silu(xBC + params["conv_b"]).astype(u.dtype)
+    gn = (xBC.shape[-1] - d_inner) // 2
+    x = xBC[..., :d_inner].reshape(b, s, n_heads, d_inner // n_heads)
+    Bm = xBC[..., d_inner:d_inner + gn].reshape(b, s, n_groups, gn // n_groups)
+    Cm = xBC[..., d_inner + gn:].reshape(b, s, n_groups, gn // n_groups)
+    return z, x, Bm, Cm, dt, new_conv
+
+
+def _mamba2_out(params: Params, y: jax.Array, z: jax.Array, n_groups: int,
+                eps: float = 1e-6) -> jax.Array:
+    """out_proj of RMSNorm(y * silu(z)), normed over each group's part of
+    d_inner. y: (b, s, d_inner) f32."""
+    g = y * jax.nn.silu(z.astype(jnp.float32))
+    gs = g.reshape(*g.shape[:-1], n_groups, -1)
+    gs = gs * jax.lax.rsqrt(jnp.mean(jnp.square(gs), axis=-1, keepdims=True) + eps)
+    g = gs.reshape(g.shape) * (1.0 + params["norm"])
+    return jnp.einsum("bsk,kd->bsd", g.astype(z.dtype), params["out_proj"])
+
+
+@scopes.scoped(scopes.SSM_SSD)
+def ssd(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array, C: jax.Array,
+        chunk: int = SSD_CHUNK) -> Tuple[jax.Array, jax.Array]:
+    """The scan ``h_t = exp(dt_t A) h_{t-1} + dt_t x_t B_t^T``, ``y_t = h_t C_t``
+    from a zero state, by the state-space duality over chunks of ``chunk``.
+
+    x: (b, s, H, P); dt: (b, s, H) f32; A: (H,) f32; B, C: (b, s, G, N),
+    head ``h`` reading group ``h // (H / G)``. Per chunk the output is the
+    masked product ``(L o C B^T) (dt x)`` with ``L_ij = exp(sum_{k=j+1..i}
+    dt_k A)``, plus the state entering the chunk read through C and decayed;
+    the chunks' end states are products too, and only they pass through a
+    sequential recurrence, over ``s / chunk`` steps. Decays and their sums are
+    f32; the products take ``x``'s dtype with f32 accumulation. A short last
+    chunk is padded with ``dt = 0``, which neither decays nor adds. Returns y
+    (b, s, H, P) f32 and the last state (b, H, P, N) f32.
+    """
+    f32 = jnp.float32
+    b, s, H, P = x.shape
+    G, N = B.shape[-2:]
+    hg, L = H // G, min(chunk, s)
+    pad = -s % L
+    if pad:
+        x, dt, B, C = (jnp.pad(t, [(0, 0), (0, pad)] + [(0, 0)] * (t.ndim - 2))
+                       for t in (x, dt, B, C))
+    nc = (s + pad) // L
+    mm = x.dtype
+    xdt = (x.astype(f32) * dt[..., None]).reshape(b, nc, L, G, hg, P)
+    Bc, Cc = B.reshape(b, nc, L, G, N), C.reshape(b, nc, L, G, N)
+    # cumulative log-decay within each chunk, heads before positions
+    acum = jnp.cumsum((dt * A).reshape(b, nc, L, G, hg).transpose(0, 1, 3, 4, 2), axis=-1)
+
+    # within the chunk: (L o C B^T) (dt x)
+    seg = acum[..., :, None] - acum[..., None, :]  # (b, c, G, hg, L_i, L_j)
+    causal = jnp.tril(jnp.ones((L, L), bool))
+    cb = jnp.einsum("bcign,bcjgn->bcgij", Cc, Bc, preferred_element_type=f32)
+    m = (jnp.exp(jnp.where(causal, seg, -jnp.inf)) * cb[:, :, :, None]).astype(mm)
+    y = jnp.einsum("bcghij,bcjghp->bcighp", m, xdt.astype(mm), preferred_element_type=f32)
+
+    # each chunk's end state, from a zero state at its start
+    to_end = jnp.exp(acum[..., -1:] - acum).transpose(0, 1, 4, 2, 3)  # (b, c, L, G, hg)
+    states = jnp.einsum("bcjgn,bcjghp->bcghpn", Bc, (xdt * to_end[..., None]).astype(mm),
+                        preferred_element_type=f32)
+
+    # the states entering each chunk: a recurrence over the chunks
+    def carry(h, inputs):
+        state, decay = inputs
+        return decay[..., None, None] * h + state, h
+
+    h_last, h_in = jax.lax.scan(carry, jnp.zeros((b, G, hg, P, N), f32),
+                                (states.swapaxes(0, 1), jnp.exp(acum[..., -1]).swapaxes(0, 1)))
+    # ... read at each position through C, decayed from the chunk's start
+    y_in = jnp.einsum("bcign,cbghpn->bcighp", Cc, h_in.astype(mm), preferred_element_type=f32)
+    y = y + y_in * jnp.exp(acum).transpose(0, 1, 4, 2, 3)[..., None]
+    return y.reshape(b, nc * L, H, P)[:, :s], h_last.reshape(b, H, P, N)
 
 
 @scopes.scoped(scopes.SSM)
-def mamba2_forward(params: Params, x: jax.Array, d_state: int, head_dim: int = 64,
-                   chunk: int = 16, sequential: bool = False) -> jax.Array:
-    b, s, _ = x.shape
-    d_inner = params["out_proj"].shape[0]
-    n_heads = d_inner // head_dim
-    xc, z, Bm, Cm, dt, _ = _mamba2_inputs(params, x)
-    xc = shard_hint(xc, "batch", None, "model")
-    A = -jnp.exp(params["A_log"])  # (h,)
-
-    def make_ab(ci):
-        x_c, B_c, _, dt_c = ci  # (b,c,di), (b,c,n), ·, (b,c,h)
-        dA = jnp.exp(dt_c * A)[..., None, None]  # (b, c, h, 1, 1)
-        xh = x_c.reshape(*x_c.shape[:2], n_heads, head_dim).astype(jnp.float32)
-        xh = shard_hint(xh, "batch", None, "model", None)
-        dBx = (dt_c[..., None] * xh)[..., None] * B_c[:, :, None, None, :]
-        return shard_hint(dA, "batch", None, "model", None, None), \
-            shard_hint(dBx, "batch", None, "model", None, None)
-
-    def emit(h_all, ci):
-        x_c, _, C_c, _ = ci
-        xh = x_c.reshape(*x_c.shape[:2], n_heads, head_dim).astype(jnp.float32)
-        xh = shard_hint(xh, "batch", None, "model", None)
-        y = jnp.einsum("bshdn,bsn->bshd", h_all, C_c)
-        y = y + params["D"][:, None] * xh
-        return shard_hint(y.reshape(*x_c.shape[:2], d_inner), "batch", None, "model")
-
-    h0 = shard_hint(jnp.zeros((b, n_heads, head_dim, d_state), jnp.float32),
-                    "batch", "model", None, None)
-    y, _ = chunked_selective_scan((xc, Bm, Cm, dt), make_ab, h0, chunk, emit,
-                                  sequential=sequential)
-    y = (y * jax.nn.silu(z.astype(jnp.float32))).astype(x.dtype)
-    return jnp.einsum("bsd,dk->bsk", y, params["out_proj"])
+def mamba2_forward(params: Params, u: jax.Array, n_groups: int) -> jax.Array:
+    """The Mamba2 mixer over a whole sequence of normed inputs u (b, s, d)."""
+    b, s, _ = u.shape
+    z, x, Bm, Cm, dt, _ = _mamba2_in(params, u, n_groups)
+    y, _ = ssd(x, dt, -jnp.exp(params["A_log"]), Bm, Cm)
+    y = y + params["D"][:, None] * x.astype(jnp.float32)
+    return _mamba2_out(params, y.reshape(b, s, -1), z, n_groups)
 
 
-def init_mamba2_cache(batch: int, d_inner: int, d_state: int, conv_width: int,
-                      dtype: Any, head_dim: int = 64) -> Dict[str, jax.Array]:
-    n_heads = d_inner // head_dim
+def init_mamba2_cache(batch: int, d_inner: int, d_state: int, n_groups: int,
+                      conv_width: int, dtype: Any,
+                      head_dim: int = MAMBA2_HEAD_DIM) -> Dict[str, jax.Array]:
     return {
-        "conv": jnp.zeros((batch, conv_width - 1, d_inner), dtype),
-        "ssm": jnp.zeros((batch, n_heads, head_dim, d_state), jnp.float32),
+        "conv": jnp.zeros((batch, conv_width - 1, d_inner + 2 * n_groups * d_state), dtype),
+        "ssm": jnp.zeros((batch, d_inner // head_dim, head_dim, d_state), jnp.float32),
     }
 
 
 @scopes.scoped(scopes.SSM)
-def mamba2_decode(params: Params, x: jax.Array, cache: Dict[str, jax.Array],
-                  d_state: int, head_dim: int = 64) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    b = x.shape[0]
-    d_inner = params["out_proj"].shape[0]
-    n_heads = d_inner // head_dim
-    xc, z, Bm, Cm, dt, new_conv = _mamba2_inputs(params, x, cache["conv"])
-    A = -jnp.exp(params["A_log"])
-    dA = jnp.exp(dt[:, 0] * A)  # (b, h)
-    xh = xc[:, 0].reshape(b, n_heads, head_dim).astype(jnp.float32)
-    dBx = (dt[:, 0, :, None] * xh)[..., None] * Bm[:, 0][:, None, None, :]
-    h = dA[..., None, None] * cache["ssm"] + dBx
-    y = jnp.einsum("bhdn,bn->bhd", h, Cm[:, 0])
-    y = y + params["D"][:, None] * xh
-    y = y.reshape(b, 1, d_inner)
-    y = (y * jax.nn.silu(z.astype(jnp.float32))).astype(x.dtype)
-    out = jnp.einsum("bsd,dk->bsk", y, params["out_proj"])
-    return out, {"conv": new_conv.astype(cache["conv"].dtype), "ssm": h}
+def mamba2_decode(params: Params, u: jax.Array, cache: Dict[str, jax.Array],
+                  n_groups: int) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """One token of the Mamba2 mixer, u (b, 1, d), one recurrence step."""
+    b = u.shape[0]
+    z, x, Bm, Cm, dt, new_conv = _mamba2_in(params, u, n_groups, cache["conv"])
+    _, _, H, P = x.shape
+    N = Bm.shape[-1]
+    x = x[:, 0].astype(jnp.float32).reshape(b, n_groups, H // n_groups, P)
+    decay = jnp.exp(dt[:, 0] * -jnp.exp(params["A_log"])).reshape(b, n_groups, -1)
+    h = cache["ssm"].reshape(b, n_groups, H // n_groups, P, N)
+    h = (decay[..., None, None] * h + (dt[:, 0].reshape(decay.shape)[..., None] * x)[..., None]
+         * Bm[:, 0, :, None, None, :].astype(jnp.float32))
+    y = jnp.einsum("bghpn,bgn->bghp", h, Cm[:, 0].astype(jnp.float32))
+    y = (y + params["D"].reshape(n_groups, -1, 1) * x).reshape(b, 1, H * P)
+    out = _mamba2_out(params, y, z, n_groups)
+    return out, {"conv": new_conv.astype(cache["conv"].dtype), "ssm": h.reshape(b, H, P, N)}

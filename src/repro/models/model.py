@@ -10,12 +10,15 @@ Families
   moe     : dense attention + top-k expert MLP (arctic adds a dense residual)
   ssm     : attention-free Mamba1 stack (falcon-mamba)
   hybrid  : Mamba2 blocks with a shared attention block every k layers (zamba2)
+  interleaved : Mamba2 and attention layers in a repeating pattern, each
+            layer followed by its own MLP, with muP multipliers (granite-4.0-h)
   audio   : whisper enc-dec backbone (frame embeddings stubbed upstream)
   vlm     : paligemma — gemma decoder over [patch embeddings; text], prefix
             attends bidirectionally, suffix causally
 """
 from __future__ import annotations
 
+import itertools
 from dataclasses import dataclass
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
@@ -65,6 +68,17 @@ class Model:
             self.n_super = cfg.n_layers // cfg.attn_every
             self.mamba_per_super = cfg.attn_every - 1
             self.n_tail = cfg.n_layers - self.n_super * cfg.attn_every
+        if cfg.family == "interleaved":
+            period = len(cfg.layer_pattern)
+            if not period or cfg.n_layers % period or set(cfg.layer_pattern) - {"M", "A"}:
+                raise ValueError(f"{cfg.name}: {cfg.n_layers} layers in periods of "
+                                 f"{cfg.layer_pattern!r} ('M' Mamba2, 'A' attention)")
+            self.n_periods = cfg.n_layers // period
+            # the period as runs of one kind of layer: "MMMMMAMMMM" -> M5 A1 M4
+            self.runs = [(k, len(list(g))) for k, g in itertools.groupby(cfg.layer_pattern)]
+        elif (cfg.residual_multiplier, cfg.attention_multiplier, cfg.use_rope) != (1.0, 0.0, True):
+            raise ValueError(f"{cfg.name}: residual and attention multipliers and NoPE are "
+                             "built for the interleaved family only")
         if cfg.alt_local_global:
             assert cfg.n_layers % 2 == 0
 
@@ -161,10 +175,13 @@ class Model:
                                 cfg.dense_ff if cfg.moe_dense_residual else 0),
             }
 
+        def init_mamba2(k):
+            return mamba_lib.init_mamba2(k, cfg.d_model, cfg.d_inner, cfg.ssm_state,
+                                         cfg.ssm_groups, cfg.conv_width, dt)
+
         def init_mamba_block(k):
             if cfg.ssm_version == 2:
-                body = mamba_lib.init_mamba2(k, cfg.d_model, cfg.d_inner, cfg.ssm_state,
-                                             cfg.conv_width, dt)
+                body = init_mamba2(k)
             else:
                 body = mamba_lib.init_mamba1(k, cfg.d_model, cfg.d_inner, cfg.ssm_state,
                                              cfg.dt_rank, cfg.conv_width, dt)
@@ -195,6 +212,22 @@ class Model:
                 params["tail_blocks"] = jax.vmap(init_mamba_block)(
                     jax.random.split(keys[3], self.n_tail)
                 )
+        elif fam == "interleaved":
+            def init_layer(kind):
+                def init(k):
+                    k1, k2 = jax.random.split(k)
+                    mixer = {"attn": init_attn(k1)} if kind == "A" else {"mamba": init_mamba2(k1)}
+                    return {"ln1": jnp.zeros((cfg.d_model,), jnp.float32), **mixer,
+                            "ln2": jnp.zeros((cfg.d_model,), jnp.float32),
+                            "mlp": init_mlp(k2, cfg.d_model, cfg.d_ff, dt)}
+                return init
+
+            params["runs"] = []  # each run's layers, (n_periods, run length, ...)
+            for i, (kind, n) in enumerate(self.runs):
+                ks = jax.random.split(jax.random.fold_in(keys[1], i), self.n_periods * n)
+                params["runs"].append(jax.tree.map(
+                    lambda a, n=n: a.reshape(self.n_periods, n, *a.shape[1:]),
+                    jax.vmap(init_layer(kind))(ks)))
         elif fam == "audio":
             params["enc_blocks"] = jax.vmap(init_dense_block)(
                 jax.random.split(keys[1], cfg.n_encoder_layers)
@@ -222,6 +255,21 @@ class Model:
     # ======================================================================
     def _maybe_remat(self, fn):
         return jax.checkpoint(fn) if self.cfg.remat else fn
+
+    def _residual(self, x: jax.Array, h: jax.Array) -> jax.Array:
+        r = self.cfg.residual_multiplier
+        return x + h if r == 1.0 else x + r * h
+
+    def _embed(self, params: Params, tokens: jax.Array) -> jax.Array:
+        x = embed(params["embed"], tokens).astype(self.dtype)
+        m = self.cfg.embedding_multiplier
+        return x if m == 1.0 else x * m
+
+    def _logits(self, params: Params, x: jax.Array) -> jax.Array:
+        cfg = self.cfg
+        x = rms_norm(x, params["final_norm"])
+        logits = logits_from_embedding(params["embed"], x, cfg.vocab, cfg.final_logit_softcap)
+        return logits if cfg.logits_scaling == 1.0 else logits / cfg.logits_scaling
 
     def _attn_kwargs(self, window: int) -> Dict[str, Any]:
         return dict(
@@ -253,8 +301,7 @@ class Model:
         if fam == "audio":
             return self._forward_encdec(params, batch), aux
 
-        tokens = batch.tokens
-        x = embed(params["embed"], tokens).astype(self.dtype)
+        x = self._embed(params, batch.tokens)
         prefix_len = 0
         if fam == "vlm" and batch.patch_embeddings is not None:
             x = jnp.concatenate([batch.patch_embeddings.astype(self.dtype), x], axis=1)
@@ -313,29 +360,50 @@ class Model:
             x, _ = jax.lax.scan(self._maybe_remat(body), x, params["blocks"])
         elif fam == "hybrid":
             x = self._forward_hybrid(params, x, positions)
+        elif fam == "interleaved":
+            def period(carry, runs):
+                for (kind, _), blocks in zip(self.runs, runs):
+                    carry, _ = jax.lax.scan(self._interleaved_layer(kind), carry, blocks)
+                return carry, None
+
+            (x, _), _ = jax.lax.scan(period, (x, positions), params["runs"])
         else:
             raise ValueError(fam)
 
         with jax.named_scope(scopes.LM_HEAD_LOSS):
-            x = rms_norm(x, params["final_norm"])
-            logits = logits_from_embedding(params["embed"], x, cfg.vocab,
-                                           cfg.final_logit_softcap)
+            logits = self._logits(params, x)
             if fam == "vlm" and prefix_len:
                 logits = logits[:, prefix_len:]
         return logits, aux
 
+    def _interleaved_layer(self, kind: str):
+        """One layer of the interleaved stack, the mixer of ``kind`` ("M"
+        Mamba2, "A" attention) and then the MLP, each on a normed input and
+        added to the residual stream times the residual multiplier."""
+        cfg = self.cfg
+
+        def body(carry, block):
+            x, positions = carry
+            u = rms_norm(x, block["ln1"])
+            if kind == "A":
+                h = attn_lib.attention(
+                    block["attn"], u, positions, causal=True, positions_are_rows=True,
+                    use_rope=cfg.use_rope, scale=cfg.attention_multiplier or None,
+                    **self._attn_kwargs(0))
+            else:
+                h = mamba_lib.mamba2_forward(block["mamba"], u, cfg.ssm_groups)
+            x = self._residual(x, h)
+            x = self._residual(x, mlp(block["mlp"], rms_norm(x, block["ln2"])))
+            return (self._shard_acts(x), positions), None
+
+        return self._maybe_remat(body)
+
     def _forward_hybrid(self, params: Params, x: jax.Array, positions: jax.Array) -> jax.Array:
         cfg = self.cfg
-        b = x.shape[0]
-        chunk = mamba_lib.pick_chunk(
-            b, (cfg.d_inner // 64) * 64 * cfg.ssm_state,
-            256 << 20 if self.act_sharding is None
-            else (256 << 20) * self._shard_divisor())
 
         def mamba_step(x, block):
             y = mamba_lib.mamba2_forward(block["body"], rms_norm(x, block["ln"]),
-                                         cfg.ssm_state, chunk=chunk,
-                                         sequential=cfg.ssm_sequential_scan)
+                                         cfg.ssm_groups)
             return self._shard_acts(x + y)
 
         shared = params["shared_attn"]
@@ -382,7 +450,7 @@ class Model:
                                    params["enc_blocks"])
         enc = rms_norm(enc, params["enc_final_norm"])
 
-        x = embed(params["embed"], batch.tokens).astype(self.dtype)
+        x = self._embed(params, batch.tokens)
         s = x.shape[1]
         positions = jnp.broadcast_to(jnp.arange(s), (b, s))
 
@@ -404,8 +472,7 @@ class Model:
 
         (x, _), _ = jax.lax.scan(self._maybe_remat(dec_body), (x, positions), params["blocks"])
         with jax.named_scope(scopes.LM_HEAD_LOSS):
-            x = rms_norm(x, params["final_norm"])
-            return logits_from_embedding(params["embed"], x, cfg.vocab)
+            return self._logits(params, x)
 
     # ======================================================================
     # losses
@@ -447,8 +514,17 @@ class Model:
             c = mamba_lib.init_mamba1_cache(batch, cfg.d_inner, cfg.ssm_state, cfg.conv_width, dt)
             return {"mamba": jax.tree.map(
                 lambda a: jnp.zeros((cfg.n_layers, *a.shape), a.dtype), c)}
+        if fam == "interleaved":
+            def run_cache(kind: str, n: int) -> Params:
+                c = (mamba_lib.init_mamba2_cache(batch, cfg.d_inner, cfg.ssm_state,
+                                                 cfg.ssm_groups, cfg.conv_width, dt)
+                     if kind == "M" else attn_lib.init_kv_cache(batch, cache_len, kv, hd, dt))
+                return jax.tree.map(lambda a: jnp.zeros((self.n_periods, n, *a.shape), a.dtype), c)
+
+            return {"runs": [run_cache(kind, n) for kind, n in self.runs]}
         if fam == "hybrid":
-            c = mamba_lib.init_mamba2_cache(batch, cfg.d_inner, cfg.ssm_state, cfg.conv_width, dt)
+            c = mamba_lib.init_mamba2_cache(batch, cfg.d_inner, cfg.ssm_state, cfg.ssm_groups,
+                                            cfg.conv_width, dt)
             out = {
                 "mamba": jax.tree.map(
                     lambda a: jnp.zeros((self.n_super, self.mamba_per_super, *a.shape), a.dtype), c),
@@ -472,7 +548,7 @@ class Model:
         """tokens: (b, 1); positions: (b,) absolute index of the new token."""
         cfg = self.cfg
         fam = cfg.family
-        x = embed(params["embed"], tokens).astype(self.dtype)
+        x = self._embed(params, tokens)
         kw = dict(softcap=cfg.attn_logit_softcap, rope_theta=cfg.rope_theta)
 
         def attn_decode(block, x, c, window):
@@ -526,7 +602,7 @@ class Model:
 
             def mstep(x, blk, c):
                 y, c2 = mamba_lib.mamba2_decode(blk["body"], rms_norm(x, blk["ln"]),
-                                                c, cfg.ssm_state)
+                                                c, cfg.ssm_groups)
                 return x + y, c2
 
             def super_body(x, xs):
@@ -553,6 +629,30 @@ class Model:
 
                 x, tc = jax.lax.scan(tail, x, (params["tail_blocks"], cache["tail"]))
                 new_cache["tail"] = tc
+        elif fam == "interleaved":
+            def layer(kind):
+                def body(x, xs):
+                    blk, c = xs
+                    u = rms_norm(x, blk["ln1"])
+                    if kind == "A":
+                        h, c = attn_lib.decode_attention(
+                            blk["attn"], u, positions, c, use_rope=cfg.use_rope,
+                            scale=cfg.attention_multiplier or None, **kw)
+                    else:
+                        h, c = mamba_lib.mamba2_decode(blk["mamba"], u, c, cfg.ssm_groups)
+                    x = self._residual(x, h)
+                    return self._residual(x, mlp(blk["mlp"], rms_norm(x, blk["ln2"]))), c
+                return body
+
+            def period(x, xs):
+                caches = []
+                for (kind, _), blocks, c in zip(self.runs, *xs):
+                    x, c = jax.lax.scan(layer(kind), x, (blocks, c))
+                    caches.append(c)
+                return x, caches
+
+            x, runs = jax.lax.scan(period, x, (params["runs"], cache["runs"]))
+            new_cache = {"runs": runs}
         elif fam == "audio":
             def body(x, xs):
                 block, c, ck, cv = xs
@@ -570,9 +670,7 @@ class Model:
         else:
             raise ValueError(fam)
 
-        x = rms_norm(x, params["final_norm"])
-        logits = logits_from_embedding(params["embed"], x, cfg.vocab, cfg.final_logit_softcap)
-        return logits, new_cache
+        return self._logits(params, x), new_cache
 
 
 def build_model(cfg: ArchConfig, shape_name: str = "") -> Model:

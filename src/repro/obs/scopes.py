@@ -17,7 +17,9 @@ Model layers (inside the shared functions, so every family carries them):
   of the loop (``models/attention.py``);
 * :data:`MLP` — the SwiGLU MLP;
 * :data:`MOE` — the expert block (router, dispatch, experts, combine);
-* :data:`SSM` — the Mamba block;
+* :data:`SSM` — the Mamba block, with :data:`SSM_SSD` inside a Mamba2 block
+  around its chunked scan (intra-chunk products, chunk states, the pass over
+  chunks and their read-out), apart from projections, conv and gated norm;
 * :data:`LM_HEAD_LOSS` — final norm, tied readout and cross-entropy.
 
 Trainer step (``dfl/trainer.py``): :data:`GRADS` (``value_and_grad`` and the
@@ -41,6 +43,7 @@ ATTENTION_FLASH = "attention.flash"
 MLP = "mlp"
 MOE = "moe"
 SSM = "ssm"
+SSM_SSD = "ssm.ssd"
 LM_HEAD_LOSS = "lm_head_loss"
 
 GRADS = "dfl.grads"

@@ -75,6 +75,30 @@ def test_fused_matches_scan_forward_and_backward(on_tpu):
     assert np.abs(g - ref).max() <= 0.03 * np.abs(ref).max()
 
 
+def test_nope_at_a_set_scale_takes_the_kernel_and_matches_the_scan(on_tpu):
+    """granite-4.0-h's attention: no positional embedding, scores scaled by
+    1/64 rather than head_dim ** -0.5. It takes the kernel, at its cell's
+    8192 too, and the kernel's scale is the one asked for."""
+    params, x, cot, positions = _inputs()
+    x = x * 16  # scores large enough that their scale shows in the output
+    kw = dict(use_rope=False, scale=1 / 64)
+    assert _takes_kernel(**kw) and _takes_kernel(s=8192, grad=True, **kw)
+    ref_out, (ref_dp, ref_dx) = jax.jit(
+        lambda p, x_: _loss_and_grads(p, x_, cot, positions, **kw))(params, x)
+    with pltpu.force_tpu_interpret_mode():
+        out, (dp, dx) = jax.jit(lambda p, x_: _loss_and_grads(
+            p, x_, cot, positions, positions_are_rows=True, **kw))(params, x)
+        default_scale, _ = jax.jit(lambda p, x_: _loss_and_grads(
+            p, x_, cot, positions, positions_are_rows=True, use_rope=False))(params, x)
+    np.testing.assert_allclose(_f32(out), _f32(ref_out), rtol=0.05, atol=0.02)
+    for name in ("wq", "wk", "wv", "wo"):
+        g, ref = _f32(dp[name]), _f32(ref_dp[name])
+        assert np.abs(g - ref).max() <= 0.03 * np.abs(ref).max(), name
+    g, ref = _f32(dx), _f32(ref_dx)
+    assert np.abs(g - ref).max() <= 0.03 * np.abs(ref).max()
+    assert np.abs(_f32(default_scale) - _f32(out)).max() > 0.1 * np.abs(_f32(out)).max()
+
+
 def _takes_kernel(s: int = S, positions_are_rows: bool = True, hd: int = HD,
                   grad: bool = False, **kw) -> bool:
     """Whether the traced call, or with ``grad`` its gradient, holds the

@@ -8,6 +8,9 @@ these tests say nothing of results or speed.
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and each test worker imports every file.
 """
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -202,3 +205,35 @@ def test_attention_head_dims_compile(arch, fused, one_chip, attention_backend):
     attention_backend(fused=True)
     hlo = _attention_layer_grad(arch, 1, 2048, lambda rank, batch: one_chip).as_text()
     assert hlo.count("tpu_custom_call") == (4 if fused else 0)
+
+
+def test_granite_4_h_cell_step_fits_one_chip(topo, attention_backend):
+    """The granite-4.0-h-micro cell's whole DFL step (1 x 8192 tokens, ten
+    layers in the published order, 12,544 rows of the vocabulary, AdamW with
+    float32 master weights and moments): the compiler's memory fits a v5e
+    with room, the attention layer holds the four flash kernels, and the
+    chunked SSD never builds a (s, H, P, N) f32 tensor of the whole sequence's
+    states: its largest are the masked within-chunk products, (s, H, 256)."""
+    from repro.configs import get_arch
+    from repro.dfl import DFLConfig, DFLTrainer
+    from repro.models import Batch, build_model
+
+    attention_backend(fused=True)
+    mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    cfg = get_arch("granite-4.0-h-micro-vocab8").replace(n_layers=10)
+    trainer = DFLTrainer(build_model(cfg), mesh, DFLConfig(lr=3e-4, warmup=0))
+    state = jax.eval_shape(trainer.init_state, jax.random.PRNGKey(0))
+    tok = jax.ShapeDtypeStruct((1, 8192), jnp.int32)
+    batch = Batch(tokens=tok, labels=tok)
+    compiled = trainer.jitted_train_step(state, batch).lower(state, batch).compile()
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes
+            - mem.alias_size_in_bytes) <= 15.5 * 2**30
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") == 4
+    f32 = [math.prod(int(d) for d in dims.split(","))
+           for dims in re.findall(r"f32\[([\d,]+)\]", hlo)]
+    s, heads, head_dim, state_size = 8192, 64, 64, 128
+    assert max(f32) < s * heads * head_dim * state_size
+    assert max(f32) == s * heads * 256

@@ -77,6 +77,24 @@ def test_step_carries_layer_scopes_forward_and_backward(step_text):
     assert not any(scopes.GRADS in _segments(n) for n in under[scopes.UPDATE])
 
 
+def test_mamba2_step_carries_ssm_and_ssd_scopes():
+    """In a Mamba2 layer (granite-4.0-h's stack at a tiny width), the chunked
+    scan sits in ``ssm.ssd`` inside ``ssm``, forward and backward, apart from
+    the projections, conv and gated norm, which ``ssm`` holds alone."""
+    cfg = get_arch("granite-4.0-h-micro").smoke_variant().replace(**dict(TINY, n_layers=10))
+    trainer = DFLTrainer(build_model(cfg), make_local_mesh((1, 1), ("data", "model")))
+    state = jax.eval_shape(trainer.init_state, jax.random.PRNGKey(0))
+    tok = jax.ShapeDtypeStruct((1, SEQ), jnp.int32)
+    batch = Batch(tokens=tok, labels=tok)
+    names = _op_names(trainer.jitted_train_step(state, batch).lower(state, batch)
+                      .compile().as_text())
+    ssd = [n for n in names if scopes.SSM_SSD in _segments(n)]
+    assert ssd and all(scopes.SSM in _segments(n) for n in ssd if n.startswith("jit("))
+    assert any("transpose(" in n for n in ssd)
+    assert any(scopes.SSM in _segments(n) and scopes.SSM_SSD not in _segments(n)
+               for n in names)
+
+
 def test_scopes_change_only_metadata(step_text, monkeypatch):
     monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
     plain = _compiled_step_text()
